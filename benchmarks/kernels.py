@@ -202,8 +202,8 @@ def run_tune(quick=True, out_path=None):
                 f"gate (err={w['err']:.2e} grad_err={w['grad_err']:.2e})")
         if "bm" in w:
             blocks_seen.add((w["bm"], w["bn"]))
-        roof = roofline.ff_dense_roofline(r["M"], r["K"], r["N"],
-                                          platform=platform)
+        roof = roofline.ff_dense_roofline(
+            r["M"], r["K"], r["N"], device_kind=jax.devices()[0].device_kind)
         r["roofline"] = {
             "roof_s": roof["roof_s"], "bound": roof["bound"],
             "winner_pct_of_roof": roofline.pct_of_roofline(

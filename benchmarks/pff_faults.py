@@ -23,7 +23,11 @@ promises, writing ``BENCH_pff_faults.json`` (``make fault-smoke``):
      surviving manifests; the resumed process itself gates its final
      weights bit-exact against the fault-free reference (the
      ``pff_exec`` CLI's ``--fault-plan``/``--resume-from`` path — the
-     same one ``tests/test_pff_faults.py`` drives).
+     same one ``tests/test_pff_faults.py`` drives). These children are a
+     CPU correctness gate: they start with ``JAX_PLATFORMS=cpu`` and the
+     4-fake-device flag set explicitly, because on a TPU host this
+     parent process already holds the chip and a child reaching for it
+     would fail or hang.
 
 Needs >= 4 devices (export
 ``XLA_FLAGS=--xla_force_host_platform_device_count=4`` before jax is
@@ -77,6 +81,9 @@ def _bit_gate(label, ref, res, failures):
 def _kill_resume_row(schedule, nodes, splits, n_train, failures):
     """Hard-kill a CLI run mid-chapter, resume it, parse the verdicts."""
     env = dict(os.environ)
+    # the parent holds the accelerator (if any): keep the children on
+    # the CPU with 4 faked devices
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = _SRC + os.pathsep + env.get("PYTHONPATH", "")
     base = [sys.executable, "-m", "repro.core.pff_exec",

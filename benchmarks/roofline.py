@@ -21,22 +21,24 @@ NOTE = {
     "collective": "resharding or larger per-device batch cuts ICI bytes",
 }
 
-# Nominal (peak_flops/s, peak_bytes/s) per platform for the kernel-tune
-# %-of-roofline column. TPU = v5e MXU bf16 peak + HBM BW; CPU = a
-# round-number container-class estimate (2 cores x AVX2 FMA, DDR) —
-# documented approximations: the column is for comparing shapes and
-# trajectories, not certifying hardware.
+# Nominal (peak_flops/s, peak_bytes/s) per ``jax.Device.device_kind``
+# for the kernel-tune %-of-roofline column. "TPU v5 lite" (v5e): bf16
+# MXU peak + HBM bandwidth from Google Cloud's "TPU v5e" page; "cpu": a
+# round-number container-class estimate (2 cores x AVX2 FMA, DDR). A
+# device with no row is an error, never a default.
 PEAKS = {
-    "tpu": (1.97e14, 8.19e11),
+    "TPU v5 lite": (1.97e14, 8.19e11),
     "cpu": (1.0e11, 2.0e10),
-    "gpu": (1.0e13, 1.0e12),
 }
 
 
-def ff_dense_roofline(M, K, N, *, platform="cpu", dtype_bytes=4):
+def ff_dense_roofline(M, K, N, *, device_kind, dtype_bytes=4):
     """Analytic roofline for ONE fused ff_dense fwd + fused-bwd step
     (what the autotuner times): flops/bytes totals, the compute and
     memory terms, and the max-of-terms min time in seconds."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no peak rates for device_kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}")
     # fwd: matmul 2MKN + bias/relu/square-accumulate ~3MN
     # bwd: dy rebuild ~4MN + three products (dx, dw via 2MKN each)
     flops = 3 * (2 * M * K * N) + 7 * M * N
@@ -44,7 +46,7 @@ def ff_dense_roofline(M, K, N, *, platform="cpu", dtype_bytes=4):
     # dx, dw, db out (bwd) — activations never round-trip inside a step
     bytes_ = dtype_bytes * (3 * (M * K + K * N) + 3 * M * N
                             + 2 * N + 3 * M)
-    peak_f, peak_b = PEAKS.get(platform, PEAKS["cpu"])
+    peak_f, peak_b = PEAKS[device_kind]
     t_compute = flops / peak_f
     t_memory = bytes_ / peak_b
     return {

@@ -49,6 +49,8 @@ def main(argv):
         print(f"unknown --only section {only!r}; "
               f"expected one of {', '.join(SECTIONS)}")
         sys.exit(2)
+    from repro.launch import compile_cache
+    compile_cache.use_compile_cache()
     t0 = time.time()
     failures = []
 

@@ -55,8 +55,6 @@ params = transformer.init(key, cfg)
 opt = optim.adam_init(params)
 B, S = 8, 64
 inflight = pff_pod.init_inflight(cfg, B, S, stages=2)
-# NOTE: step_fn is jitted internally (two executables) — wrapping it in
-# an outer jax.jit re-fuses them and hits a jax-0.4.x GSPMD miscompile.
 step_fn = pff_pod.make_pff_pod_step(cfg, mesh, lr=1e-3)
 
 t0 = time.time()

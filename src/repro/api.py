@@ -518,12 +518,7 @@ def _fit_lm_chapters(cfg, source, *, backend, schedule, num_nodes,
 
 
 def _fit_pod(cfg, task, *, num_nodes, steps, batch, seq, lr, verbose):
-    """Beyond-paper pod-pipeline backend (transformer LM configs only).
-
-    NOTE: ``pff_pod``'s step function is jitted internally as TWO
-    executables (glue, pipeline) — this driver must NOT wrap it in an
-    outer jax.jit (jax-0.4.x GSPMD miscompile; see pff_pod docstring).
-    """
+    """Beyond-paper pod-pipeline backend (transformer LM configs only)."""
     import jax
     import jax.numpy as jnp
 

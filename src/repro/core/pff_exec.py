@@ -92,7 +92,8 @@ call ``pff.elastic_node_round``). ``ExecResult.resilience`` reports
 retries, reassignments, checkpoint/restore cost and faults injected.
 
 Observability (``repro.obs``): ``run(trace=...)`` records one
-``task:<kind>`` span per DAG task (attrs kind/layer/chapter/node),
+``task:<kind>`` span per DAG task (attrs kind/layer/chapter/node,
+and the id of the device its output landed on),
 ``handoff:*`` events from the transfer slots, ``resilience:*`` events
 and counters from the retry/checkpoint machinery, and a closing
 ``run`` span carrying the DAG shape — everything ``obs.analyze`` needs
@@ -120,6 +121,14 @@ from repro.core import ff, ff_mlp, pff, pff_dag, pff_lm, strategies
 from repro.launch import mesh as mesh_lib
 from repro.models import transformer
 from repro.obs import trace as obs_trace
+
+
+def _device_id(out):
+    """Id of the device holding a task's output (its first leaf) — the
+    ``device`` attr of task spans, which shows where each node's work
+    really ran."""
+    leaves = jax.tree_util.tree_leaves(out)
+    return next(iter(leaves[0].devices())).id if leaves else None
 
 
 @dataclasses.dataclass
@@ -452,7 +461,7 @@ class PFFExecutor:
         tr = self._tracer
         if tr.enabled:
             tr.add_span("task:" + kind, t0, kind=kind, layer=layer,
-                        chapter=chapter, node=node)
+                        chapter=chapter, node=node, device=_device_id(out))
 
     def _rtime(self, name, dt):
         """Resilience seconds: one mechanism feeds both ``_rstats``
@@ -1198,7 +1207,7 @@ class LMExecutor:
         tr = self._tracer
         if tr.enabled:
             tr.add_span("task:" + kind, t0, kind=kind, layer=layer,
-                        chapter=chapter, node=node)
+                        chapter=chapter, node=node, device=_device_id(out))
 
     def _train_task(self, k, chapter, node):
         """One per-block chapter task: assemble the full trees on the

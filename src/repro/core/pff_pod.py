@@ -126,19 +126,17 @@ def make_pff_pod_step(cfg, mesh, *, lr=1e-3, seed=0, theta=None):
 
     gspec = P("stage")          # stacked layer axis sharded over stages
 
-    # check=True: every out_specs replication claim is now sound
-    # (grads/loss pmean'd over "data", loss psum'd over "stage"), so
-    # let the checker prove it instead of trusting us. Built ONCE so the
-    # jit wrapper below caches a single executable.
-    smap2 = jax.jit(sharding.shard_map(
+    # check=True: every out_specs replication claim is sound (grads/loss
+    # pmean'd over "data", loss psum'd over "stage"), so let the checker
+    # prove it instead of trusting us.
+    pipeline = sharding.shard_map(
         pod_program, mesh=mesh,
         in_specs=(gspec, gspec, gspec, P("data"),
                   P("stage", "data"), P("data"), P()),
         out_specs=(gspec, gspec, gspec, P("stage", "data"), P()),
-        check=True))
+        check=True)
 
-    @jax.jit
-    def _prep(embed, tokens, step):
+    def prep(embed, tokens, step):
         """Negative corruption + embedding lookup (the per-step glue)."""
         tokens = tokens[:, :-1]
         B = tokens.shape[0]
@@ -149,24 +147,18 @@ def make_pff_pod_step(cfg, mesh, *, lr=1e-3, seed=0, theta=None):
             [jnp.ones((B,)), jnp.zeros((B,))]).astype(jnp.float32)
         return jnp.take(embed, x_tok, axis=0), is_pos
 
+    @jax.jit
     def step_fn(params, opt_state, batch, inflight, step):
         """params: {"embed": ..., "groups": (stacked,)}; inflight is the
-        pipeline register pytree returned by the previous call.
-
-        Already jitted INTERNALLY as two executables (glue, pipeline) —
-        do NOT wrap it in an outer jax.jit: on jax 0.4.x, fusing the
-        PRNG negative-corruption glue into the same XLA program as the
-        manually-sharded pipeline miscompiles under GSPMD (NaN weights
-        on any data x model mesh; the split is the workaround).
-        """
-        x, is_pos = _prep(params["embed"], batch["tokens"],
-                          jnp.asarray(step, jnp.int32))
+        pipeline register pytree returned by the previous call. One XLA
+        program: the glue and the manually-sharded pipeline fuse."""
+        step = jnp.asarray(step, jnp.int32)
+        x, is_pos = prep(params["embed"], batch["tokens"], step)
         gp = params["groups"][0]
         gm = opt_state["m"]["groups"][0]
         gv = opt_state["v"]["groups"][0]
-        new_gp, new_gm, new_gv, new_inflight, loss = smap2(
-            gp, gm, gv, x, inflight, is_pos,
-            jnp.asarray(step, jnp.int32))
+        new_gp, new_gm, new_gv, new_inflight, loss = pipeline(
+            gp, gm, gv, x, inflight, is_pos, step)
         new_params = dict(params)
         new_params["groups"] = (new_gp,)
         new_m = dict(opt_state["m"]); new_m["groups"] = (new_gm,)

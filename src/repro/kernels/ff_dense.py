@@ -3,7 +3,7 @@
 The Forward-Forward hot loop evaluates a dense layer AND its goodness for
 both the positive and negative batch every step. Fusing the goodness
 reduction into the matmul epilogue saves one full HBM round-trip of the
-(M, N) activations — on TPU the (bm, bn) tile is reduced to a (bm,)
+(M, N) activations — on TPU the (bm, bn) tile is reduced to a (bm, 1)
 partial in VMEM right after the MXU matmul, while the tile is still hot.
 
 Grid: (M/bm, N/bn), N innermost so the goodness partials for a row-block
@@ -30,6 +30,12 @@ all disappear as XLA dispatches.
 Tile defaults are MXU-aligned (128x128); K is streamed whole per tile —
 for the paper's [784, 2000] layers x(bm, K) + w(K, bn) comfortably fit
 VMEM (784*128*4 + 784*128*4 ~= 0.8 MB).
+
+Every block is 2-D: the bias enters as a (1, bn) block of b.reshape(1, N)
+and the goodness leaves as a (bm, 1) block of an (M, 1) output. Mosaic
+(the TPU lowering) refuses 1-D (bn,) / (bm,) blocks of these operands:
+the layout XLA gives a 1-D f32 array does not match the one the kernel
+expects. The wrapper reshapes, so callers still see b (N,) and g (M,).
 """
 from __future__ import annotations
 
@@ -54,29 +60,40 @@ NORM_EPS = 1e-8
 # guarantee; an evicted block would make the epilogue divide undefined).
 VMEM_BUDGET_BYTES = 8 * 2 ** 20
 
+# Precision of every in-kernel product (this kernel's and the fused
+# backward's). Mosaic's default f32 contraction on a TPU is not full f32:
+# on a TPU v5e the default-precision kernel missed the f32 oracle by
+# 2.4e-3 (value) and 8.7e-2 (grad), scale-normalized, and HIGHEST met it
+# at 3e-7 with no slower a step at the paper's widths. The FF objective
+# is defined in f32, so the kernels ask for it. Interpret mode on the CPU
+# computes f32 products either way.
+MATMUL_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def vmem_block_bytes(K, N, bm, bn, *, norm=False, dtype_bytes=4):
     """Resident VMEM bytes of one forward grid step for blocks (bm, bn).
 
     The single source of truth for the autotuner's candidate filter:
-    x (bm, K) + w (K, bn) + b (bn,) + the y output block + the (bm,)
-    goodness accumulator. With ``norm=True`` the y block is the WHOLE
+    x (bm, K) + w (K, bn) + the (1, bn) bias block (padded to 8
+    sublanes) + the y output block + the (bm, 1) goodness accumulator
+    (padded to 128 lanes). With ``norm=True`` the y block is the WHOLE
     (bm, Np) row (j-constant index map, see module docstring) — this is
     the VMEM row-residency invariant every tuned candidate must honor.
     """
     np_ = -(-N // bn) * bn if bn else N          # padded N
     y_cols = np_ if norm else bn
-    return (bm * K + K * bn + bn + bm * y_cols) * dtype_bytes + bm * 4
+    return ((bm * K + K * bn + 8 * bn + bm * y_cols) * dtype_bytes
+            + bm * 128 * 4)
 
 
 def _tile_y_g(x_ref, w_ref, b_ref, g_ref, j):
     """The shared per-(i, j) compute: (bm, bn) activation tile plus the
     row-block goodness accumulation into the resident g block."""
-    h = jnp.dot(x_ref[...], w_ref[...],
+    h = jnp.dot(x_ref[...], w_ref[...], precision=MATMUL_PRECISION,
                 preferred_element_type=jnp.float32)
-    h = h + b_ref[...][None, :]
+    h = h + b_ref[...]
     y = jnp.maximum(h, 0.0)
-    g_part = jnp.sum(y * y, axis=1)
+    g_part = jnp.sum(y * y, axis=1, keepdims=True)
 
     @pl.when(j == 0)
     def _init():
@@ -109,7 +126,7 @@ def _kernel_norm(x_ref, w_ref, b_ref, y_ref, g_ref, *, bn, nj):
         # block in place before it is written out — the fused epilogue.
         yy = y_ref[...].astype(jnp.float32)
         scale = jnp.sqrt(g_ref[...]) + NORM_EPS
-        y_ref[...] = (yy / scale[:, None]).astype(y_ref.dtype)
+        y_ref[...] = (yy / scale).astype(y_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret",
@@ -142,7 +159,7 @@ def ff_dense(x, w, b, *, bm=128, bn=128, interpret=True, norm=False):
     in_specs = [
         pl.BlockSpec((bm, K), lambda i, j: (i, 0)),
         pl.BlockSpec((K, bn), lambda i, j: (0, j)),
-        pl.BlockSpec((bn,), lambda i, j: (j,)),
+        pl.BlockSpec((1, bn), lambda i, j: (0, j)),
     ]
     if norm:
         kernel = functools.partial(_kernel_norm, bn=bn, nj=nj)
@@ -151,7 +168,7 @@ def ff_dense(x, w, b, *, bm=128, bn=128, interpret=True, norm=False):
     else:
         kernel = _kernel
         y_spec = pl.BlockSpec((bm, bn), lambda i, j: (i, j))
-    out_specs = [y_spec, pl.BlockSpec((bm,), lambda i, j: (i,))]
+    out_specs = [y_spec, pl.BlockSpec((bm, 1), lambda i, j: (i, 0))]
     y, g = pl.pallas_call(
         kernel,
         grid=grid,
@@ -159,8 +176,8 @@ def ff_dense(x, w, b, *, bm=128, bn=128, interpret=True, norm=False):
         out_specs=out_specs,
         out_shape=[
             jax.ShapeDtypeStruct((M, N), x.dtype),
-            jax.ShapeDtypeStruct((M,), jnp.float32),
+            jax.ShapeDtypeStruct((M, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(x, w, b)
-    return y, g
+    )(x, w, b.reshape(1, N))
+    return y, g[:, 0]

@@ -36,7 +36,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.ff_dense import NORM_EPS, ff_dense as _ff_dense_fwd
+from repro.kernels.ff_dense import (
+    MATMUL_PRECISION, NORM_EPS, ff_dense as _ff_dense_fwd,
+)
 
 
 def _bwd_kernel(x_ref, w_ref, y_ref, dyo_ref, dg_ref,
@@ -48,10 +50,11 @@ def _bwd_kernel(x_ref, w_ref, y_ref, dyo_ref, dg_ref,
     dy = jnp.where(y > 0.0, dy, 0.0)                      # (bm, N)
 
     dx_ref[...] = jnp.dot(
-        dy, w_ref[...].astype(jnp.float32).T,
+        dy, w_ref[...].astype(jnp.float32).T, precision=MATMUL_PRECISION,
         preferred_element_type=jnp.float32).astype(dx_ref.dtype)
 
     dw_part = jnp.dot(x_ref[...].astype(jnp.float32).T, dy,
+                      precision=MATMUL_PRECISION,
                       preferred_element_type=jnp.float32)  # (bk, N)
 
     @pl.when(i == 0)

@@ -29,10 +29,11 @@ from repro.launch.mesh import make_production_mesh
 from repro.models import transformer
 from repro.models.mlp import Dist
 
-# TPU v5e hardware constants (per chip)
-PEAK_FLOPS = 197e12          # bf16
-HBM_BW = 819e9               # bytes/s
-ICI_BW = 50e9                # bytes/s/link
+# Per-chip peaks of a TPU v5e ("TPU v5 lite"), the one device these
+# roofline terms describe
+V5E_PEAK_FLOPS = 197e12      # bf16
+V5E_HBM_BW = 819e9           # bytes/s
+V5E_ICI_BW = 50e9            # bytes/s/link
 
 # ---------------------------------------------------------------------------
 # Lower + compile one combination
@@ -212,9 +213,9 @@ def analyze_combo(arch, shape_name, *, multi_pod=False, compile_=True,
         xla_cost_analysis={k: float(v) for k, v in ca.items()
                            if k in ("flops", "bytes accessed")},
         model_flops=mflops,
-        compute_term_s=per_dev_flops / PEAK_FLOPS,
-        memory_term_s=per_dev_bytes / HBM_BW,
-        collective_term_s=per_dev_coll / ICI_BW,
+        compute_term_s=per_dev_flops / V5E_PEAK_FLOPS,
+        memory_term_s=per_dev_bytes / V5E_HBM_BW,
+        collective_term_s=per_dev_coll / V5E_ICI_BW,
         flops_utilization=(mflops / (per_dev_flops * chips)
                            if per_dev_flops else 0.0),
     )

@@ -149,6 +149,9 @@ def _main_lm(args):
 def main(argv=None):
     from repro import api
     from repro.core import pff_dag
+    from repro.launch import compile_cache
+
+    compile_cache.use_compile_cache()
 
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--mode", choices=("ff", "lm"), default="ff",

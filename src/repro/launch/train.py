@@ -36,6 +36,7 @@ from repro.configs import get_config
 from repro.configs.ff_mlp import FFMLPConfig
 from repro.core import pff_dag, train as train_lib
 from repro.kernels import ops
+from repro.launch import compile_cache
 from repro.models import transformer
 from repro.obs import export as obs_export, trace as obs_trace
 
@@ -150,6 +151,7 @@ def run_lm(args):
 
 
 def main():
+    compile_cache.use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--paper-mlp", action="store_true")
     ap.add_argument("--cifar", action="store_true")

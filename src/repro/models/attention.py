@@ -30,6 +30,19 @@ def _pv(p, v):
     return jnp.einsum("bkgqs,bskd->bkgqd", p, v.astype(jnp.float32))
 
 
+def _varying_like(x, *refs):
+    """``x`` marked as varying over every manual mesh axis any of
+    ``refs`` varies over. Under a ``check_vma`` shard_map a scan's
+    carry must keep one type; an online-softmax carry initialised from
+    constants is invariant while the step that mixes in q/k/v makes it
+    varying, so the initial carry is cast up front. Outside shard_map
+    no axis varies and ``x`` is returned unchanged."""
+    axes = frozenset().union(*(jax.typeof(r).vma for r in refs))
+    if not axes:
+        return x
+    return jax.lax.pcast(x, tuple(sorted(axes)), to="varying")
+
+
 def _mask(q_pos, k_pos, causal, window):
     m = jnp.ones((q_pos.shape[0], k_pos.shape[0]), jnp.bool_)
     if causal:
@@ -95,9 +108,10 @@ def chunked_attention(q, k, v, *, causal=True, window=None, q_offset=0,
             acc = acc * corr[..., None] + pv
             return (m_new, l_new, acc), None
 
-        m0 = jnp.full((B, KV, G, q_chunk), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((B, KV, G, q_chunk), jnp.float32)
-        a0 = jnp.zeros((B, KV, G, q_chunk, hd), jnp.float32)
+        m0, l0, a0 = (_varying_like(c, qc, ks, vs) for c in (
+            jnp.full((B, KV, G, q_chunk), NEG_INF, jnp.float32),
+            jnp.zeros((B, KV, G, q_chunk), jnp.float32),
+            jnp.zeros((B, KV, G, q_chunk, hd), jnp.float32)))
         (m, l, acc), _ = jax.lax.scan(
             k_body, (m0, l0, a0), (ks, vs, k_positions.reshape(nk, k_chunk)))
         l = jnp.maximum(l, 1e-30)
@@ -171,9 +185,10 @@ def _triangle_attention(q, k, v, *, causal, window, q_chunk, k_chunk):
         acc_t = jax.lax.dynamic_update_index_in_dim(acc_t, acc, i, 0)
         return (m_t, l_t, acc_t), None
 
-    m0 = jnp.full((nq, B, KV, G, qc), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((nq, B, KV, G, qc), jnp.float32)
-    a0 = jnp.zeros((nq, B, KV, G, qc, hd), jnp.float32)
+    m0, l0, a0 = (_varying_like(c, qs, ks, vs) for c in (
+        jnp.full((nq, B, KV, G, qc), NEG_INF, jnp.float32),
+        jnp.zeros((nq, B, KV, G, qc), jnp.float32),
+        jnp.zeros((nq, B, KV, G, qc, hd), jnp.float32)))
     (m_t, l_t, acc_t), _ = jax.lax.scan(body, (m0, l0, a0), pairs_arr)
     l_t = jnp.maximum(l_t, 1e-30)
     out = (acc_t / l_t[..., None]).astype(q.dtype)  # (nq, B, KV, G, qc, hd)

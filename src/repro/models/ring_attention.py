@@ -37,15 +37,6 @@ import jax.numpy as jnp
 from repro.sharding import shard_map as _shard_map
 
 
-def _axis_size(axis):
-    """Static mesh-axis size, usable for Python-level loop bounds.
-    jax >= 0.5 has jax.lax.axis_size; 0.4.x exposes it via axis_frame."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    frame = jax.core.axis_frame(axis)
-    return getattr(frame, "size", frame)
-
-
 NEG_INF = -1e30
 
 
@@ -65,7 +56,7 @@ def ring_attention_local(q, k, v, *, axis, causal=True):
     (B, Sk_local, KV, hd), sequence sharded over `axis` in device
     order. Returns (B, Sq_local, H, hd).
     """
-    P = _axis_size(axis)
+    P = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     B, Sq, H, hd = q.shape
     _, Sk, KV, _ = k.shape

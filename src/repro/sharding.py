@@ -32,15 +32,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 
 def shard_map(body, *, mesh, in_specs, out_specs, check: bool = False):
-    """Version-compat ``shard_map``: jax >= 0.6 exposes ``jax.shard_map``
-    (kw ``check_vma``); this container's 0.4.x only has the experimental
-    one (kw ``check_rep``). One shim for every call site."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check)
+    """``jax.shard_map`` with ``check_vma`` spelled ``check``: one
+    wrapper for every call site."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def _fit(spec, shape, mesh):

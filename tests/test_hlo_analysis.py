@@ -2,6 +2,7 @@
 (it is the roofline's measurement instrument)."""
 import jax
 import jax.numpy as jnp
+import pytest
 
 from repro.launch import hlo_analysis
 
@@ -47,3 +48,12 @@ def test_no_collectives_on_single_device():
     res = hlo_analysis.analyze(comp.as_text())
     assert res["collective_bytes"] == 0
     assert res["flops"] == 2 * 32 * 32 * 32
+
+
+def test_roofline_refuses_a_device_without_peaks():
+    from benchmarks import roofline
+    roof = roofline.ff_dense_roofline(128, 784, 2000,
+                                      device_kind="TPU v5 lite")
+    assert roof["roof_s"] > 0
+    with pytest.raises(KeyError, match="no peak rates"):
+        roofline.ff_dense_roofline(128, 784, 2000, device_kind="TPU v9")

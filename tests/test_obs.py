@@ -357,6 +357,16 @@ def test_nonblocking_tracer_keeps_overlap_and_drops_records(task):
     assert any(s.name.startswith("task:") for s in tr.snapshot())
 
 
+def test_task_spans_name_the_device_that_holds_the_output(task):
+    import jax
+    tr = trace_lib.Tracer(block_tasks=False)
+    _exec_fit(_cfg(), task, trace=tr)
+    devs = {s.attrs["device"] for s in tr.snapshot()
+            if s.name.startswith("task:")}
+    # every node was handed device 0, so every task's output lives there
+    assert devs == {jax.devices()[0].id}
+
+
 def test_tracing_does_not_change_the_weight_stream(task):
     from repro.core import pff_exec
     cfg = _cfg()

@@ -65,7 +65,7 @@ def test_pod_pipeline_step_finite_and_updates(setup):
     """Regression for the pod-pipeline step: the shard_map body must
     pmean grads over 'data' and psum the loss over 'stage' (unsound
     replication claims used to NaN the weights on multi-axis meshes),
-    and the split-jit step must run on a trivial 1-device mesh."""
+    and the jitted step must run on a trivial 1-device mesh."""
     from repro.core import pff_pod
     cfg, params, opt = setup
     mesh = jax.make_mesh((1, 1, 1), ("stage", "data", "model"))
