@@ -265,10 +265,11 @@ def fit(cfg, task=None, *, backend="sequential", schedule=None,
 
     _validate_strategies(cfg)
     if backend == "sequential":
-        with tracer.span("fit:sequential"):
+        with tracer.span("fit:sequential", backend=backend,
+                         schedule="sequential"):
             res = pff.run_chapter_schedule(cfg, task,
                                            probe_every=probe_every,
-                                           verbose=verbose)
+                                           verbose=verbose, tracer=tracer)
         return FitResult(backend=backend, cfg=cfg, params=res.params,
                          schedule="sequential", num_nodes=1,
                          records=res.records, test_acc=res.test_acc,
@@ -279,7 +280,8 @@ def fit(cfg, task=None, *, backend="sequential", schedule=None,
         with tracer.span("fit:federated", num_nodes=num_nodes):
             res = pff.run_federated_schedule(cfg, task, num_nodes,
                                              probe_every=probe_every,
-                                             verbose=verbose)
+                                             verbose=verbose,
+                                             tracer=tracer)
         return FitResult(backend=backend, cfg=cfg, params=res.params,
                          schedule="federated", num_nodes=num_nodes,
                          records=res.records, test_acc=res.test_acc,
@@ -289,16 +291,19 @@ def fit(cfg, task=None, *, backend="sequential", schedule=None,
     schedule = schedule or ("sequential" if num_nodes == 1
                             else "all_layers")
     if backend == "executor":
-        ex = pff_exec.PFFExecutor(cfg, task, schedule, num_nodes,
-                                  devices=devices, overlap=overlap,
-                                  resilience=resilience)
-        if serve is not None:
-            return _run_combined(cfg, ex, serve, source=None,
-                                 resume_from=resume_from,
-                                 schedule=schedule, num_nodes=num_nodes,
-                                 tracer=tracer).fit
-        res = ex.run(profile=profile, resume_from=resume_from,
-                     trace=out_trace)
+        with tracer.span("fit:executor", backend=backend,
+                         schedule=schedule):
+            with tracer.span("fit:init"):
+                ex = pff_exec.PFFExecutor(cfg, task, schedule, num_nodes,
+                                          devices=devices, overlap=overlap,
+                                          resilience=resilience)
+            if serve is not None:
+                return _run_combined(cfg, ex, serve, source=None,
+                                     resume_from=resume_from,
+                                     schedule=schedule, num_nodes=num_nodes,
+                                     tracer=tracer).fit
+            res = ex.run(profile=profile, resume_from=resume_from,
+                         trace=out_trace)
         return FitResult(backend=backend, cfg=cfg, params=res.params,
                          schedule=schedule, num_nodes=num_nodes,
                          records=res.records, test_acc=res.test_acc,
@@ -314,7 +319,7 @@ def fit(cfg, task=None, *, backend="sequential", schedule=None,
     with tracer.span("fit:simulate", schedule=schedule,
                      num_nodes=num_nodes):
         res = pff.run_chapter_schedule(cfg, task, probe_every=probe_every,
-                                       verbose=verbose)
+                                       verbose=verbose, tracer=tracer)
         sim = pff.simulate_schedule(res.records, schedule, num_nodes,
                                     comm_time=comm_time)
     return FitResult(backend=backend, cfg=cfg, params=res.params,

@@ -50,6 +50,7 @@ import numpy as np
 
 from repro import data as data_lib, optim
 from repro.core import ff, ff_mlp, pff_dag, strategies
+from repro.obs import trace as obs_trace
 
 
 # ---------------------------------------------------------------------------
@@ -76,7 +77,8 @@ class TrainResult:
 
 def run_chapter_schedule(cfg, task: data_lib.ImageTask, *, probe_every=0,
                          node_data: Optional[List[np.ndarray]] = None,
-                         num_nodes: int = 1, verbose=False) -> TrainResult:
+                         num_nodes: int = 1, verbose=False,
+                         tracer=obs_trace.NOOP) -> TrainResult:
     """Runs the canonical chapter schedule of the paper (the facade's
     ``sequential`` / ``federated`` backends — call ``repro.api.fit``).
 
@@ -86,125 +88,162 @@ def run_chapter_schedule(cfg, task: data_lib.ImageTask, *, probe_every=0,
 
     node_data: optional list of per-node index arrays (Federated PFF) —
     chapter c uses node (c % num_nodes)'s shard.
+
+    tracer: an ``obs.trace`` tracer. Every layer boundary opens a span,
+    a profiler annotation even on the default ``NOOP`` (the executor's
+    names, ``pff_exec``): ``fit:init``; per chapter ``chapter``,
+    ``chapter:inputs``, ``task:train`` (attrs layer/chapter/steps) with
+    ``task:wait`` and ``task:handoff`` inside, ``task:head``,
+    ``task:neg_gen`` with ``neg:score`` (attrs chapter/rows) inside;
+    ``fit:eval`` (attr rows) for probes and the final accuracies.
+    ``task:wait`` and ``task:handoff`` are profiler-only
+    (``obs_trace.annotate``).
     """
     good = strategies.goodness.get(cfg.goodness_fn)
     neg = strategies.negatives.get(cfg.neg_mode)
     cls = strategies.classifier.get(cfg.classifier)
-    key = jax.random.PRNGKey(cfg.seed)
-    params = ff_mlp.init(key, cfg)
-    opt = ff_mlp.opt_init(params)
     records: List[TaskRecord] = []
     history = []
 
     S = cfg.splits
     C = max(cfg.epochs // cfg.splits, 1)
-    n_layers = len(params["layers"])
-    x_all = jnp.asarray(task.x_train)
-    y_all = jnp.asarray(task.y_train)
     impl = ff_mlp.kernel_impl(cfg)
     has_neg = good.uses_negatives and neg.regenerates
+    with tracer.span("fit:init"):
+        key = jax.random.PRNGKey(cfg.seed)
+        params = ff_mlp.init(key, cfg)
+        opt = ff_mlp.opt_init(params)
+        n_layers = len(params["layers"])
+        x_all = jnp.asarray(task.x_train)
+        y_all = jnp.asarray(task.y_train)
 
-    # Hoisted out of the chapter loop: label overlays and the layer-0
-    # length-normalization are chapter-invariant (the positive overlay
-    # never changes; the negative one changes only on regeneration), so
-    # recomputing them every chapter x layer would be pure waste.
-    kneg = jax.random.fold_in(key, 999)
-    if good.uses_negatives:
-        # only the normalized forms are kept — the raw overlays would be
-        # ~190 MB of dead weight each at MNIST scale. The initial
-        # negatives pass params=None/scores=None: every strategy degrades
-        # to key-only wrong labels before a model exists (the executor
-        # does the same, so custom strategies see one uniform contract).
-        xp0 = ff_mlp._norm(ff.overlay_label(x_all, y_all, cfg.num_classes))
-        xn0 = ff_mlp._norm(neg.fn(kneg, cfg, None, x_all, y_all, None))
-    if not good.uses_negatives or cls.trains_head:
-        x_neutral = ff.overlay_neutral(x_all, cfg.num_classes)
-        if not good.uses_negatives:
-            xk0 = ff_mlp._norm(x_neutral)
+        # Hoisted out of the chapter loop: label overlays and the
+        # layer-0 length-normalization are chapter-invariant (the
+        # positive overlay never changes; the negative one changes only
+        # on regeneration), so recomputing them every chapter x layer
+        # would be pure waste.
+        kneg = jax.random.fold_in(key, 999)
+        if good.uses_negatives:
+            # only the normalized forms are kept — the raw overlays
+            # would be ~190 MB of dead weight each at MNIST scale. The
+            # initial negatives pass params=None/scores=None: every
+            # strategy degrades to key-only wrong labels before a model
+            # exists (the executor does the same, so custom strategies
+            # see one uniform contract).
+            xp0 = ff_mlp._norm(ff.overlay_label(x_all, y_all,
+                                                cfg.num_classes))
+            xn0 = ff_mlp._norm(neg.fn(kneg, cfg, None, x_all, y_all, None))
+        if not good.uses_negatives or cls.trains_head:
+            x_neutral = ff.overlay_neutral(x_all, cfg.num_classes)
+            if not good.uses_negatives:
+                xk0 = ff_mlp._norm(x_neutral)
 
     for chapter in range(S):
-        if node_data is not None:
-            idx = jnp.asarray(node_data[chapter % num_nodes])
-        else:
-            idx = None
-        # learning-rate for this chapter's mini-epochs
-        lrs = jnp.asarray([
-            optim.cooldown_lr(cfg.lr_ff, chapter * C + e, cfg.epochs,
-                              cfg.cooldown_after) for e in range(C)],
-            jnp.float32)
-        lrs_head = lrs * (cfg.lr_softmax / cfg.lr_ff)
-        kc = jax.random.fold_in(key, chapter)
+        with tracer.span("chapter", chapter=chapter):
+            with tracer.span("chapter:inputs", chapter=chapter):
+                if node_data is not None:
+                    idx = jnp.asarray(node_data[chapter % num_nodes])
+                else:
+                    idx = None
+                # learning-rate for this chapter's mini-epochs
+                lrs = jnp.asarray([
+                    optim.cooldown_lr(cfg.lr_ff, chapter * C + e,
+                                      cfg.epochs, cfg.cooldown_after)
+                    for e in range(C)], jnp.float32)
+                lrs_head = lrs * (cfg.lr_softmax / cfg.lr_ff)
+                kc = jax.random.fold_in(key, chapter)
 
-        # per-chapter inputs: activations flow layer-to-layer, extras
-        # (labels) do not
-        if good.uses_negatives:
-            acts = (xp0 if idx is None else xp0[idx],
-                    xn0 if idx is None else xn0[idx])
-            extras = ()
-        else:
-            acts = (xk0 if idx is None else xk0[idx],)
-            extras = (y_all if idx is None else y_all[idx],)
+                # per-chapter inputs: activations flow layer-to-layer,
+                # extras (labels) do not
+                if good.uses_negatives:
+                    acts = (xp0 if idx is None else xp0[idx],
+                            xn0 if idx is None else xn0[idx])
+                    extras = ()
+                else:
+                    acts = (xk0 if idx is None else xk0[idx],)
+                    extras = (y_all if idx is None else y_all[idx],)
+            steps = ff_mlp._num_batches(acts[0].shape[0],
+                                        cfg.batch_size) * C
 
-        for k in range(n_layers):
-            t0 = time.perf_counter()
-            state = good.train_chapter(
-                good.get_state(params, opt, k), acts, extras, lrs,
-                jax.random.fold_in(kc, k), cfg=cfg, epochs=C)
-            jax.block_until_ready(state[0])
-            good.set_state(params, opt, k, state)
-            if k + 1 < n_layers:
-                # propagate data through the freshly-trained layer
-                acts = tuple(ff_mlp.fwd_norm(state[0], a, impl=impl)
-                             for a in acts)
-            records.append(TaskRecord(
-                "train", k, chapter, time.perf_counter() - t0))
+            for k in range(n_layers):
+                with tracer.span("task:train", layer=k, chapter=chapter,
+                                 steps=steps):
+                    t0 = time.perf_counter()
+                    state = good.train_chapter(
+                        good.get_state(params, opt, k), acts, extras, lrs,
+                        jax.random.fold_in(kc, k), cfg=cfg, epochs=C)
+                    with obs_trace.annotate("task:wait"):
+                        jax.block_until_ready(state[0])
+                    good.set_state(params, opt, k, state)
+                    if k + 1 < n_layers:
+                        # propagate data through the freshly-trained layer
+                        with obs_trace.annotate(
+                                "task:handoff", layer=k,
+                                rows=sum(a.shape[0] for a in acts)):
+                            acts = tuple(ff_mlp.fwd_norm(state[0], a,
+                                                         impl=impl)
+                                         for a in acts)
+                    records.append(TaskRecord(
+                        "train", k, chapter, time.perf_counter() - t0))
 
-        # softmax head (trained alongside, layer-local — paper §3)
-        if cls.trains_head:
-            t0 = time.perf_counter()
-            xn_all = x_neutral if idx is None else x_neutral[idx]
-            feats = ff_mlp.softmax_feats(params["layers"], xn_all,
-                                         impl=impl)
-            params["head"], opt["head"] = ff_mlp.train_head_chapter(
-                params["head"], opt["head"], feats,
-                y_all if idx is None else y_all[idx],
-                lrs_head, jax.random.fold_in(kc, 77),
-                batch=cfg.batch_size, epochs=C)
-            jax.block_until_ready(params["head"]["w"])
-            records.append(TaskRecord(
-                "head", n_layers, chapter, time.perf_counter() - t0))
+            # softmax head (trained alongside, layer-local — paper §3)
+            if cls.trains_head:
+                with tracer.span("task:head", chapter=chapter):
+                    t0 = time.perf_counter()
+                    xn_all = x_neutral if idx is None else x_neutral[idx]
+                    feats = ff_mlp.softmax_feats(params["layers"], xn_all,
+                                                 impl=impl)
+                    params["head"], opt["head"] = ff_mlp.train_head_chapter(
+                        params["head"], opt["head"], feats,
+                        y_all if idx is None else y_all[idx],
+                        lrs_head, jax.random.fold_in(kc, 77),
+                        batch=cfg.batch_size, epochs=C)
+                    with obs_trace.annotate("task:wait"):
+                        jax.block_until_ready(params["head"]["w"])
+                    records.append(TaskRecord(
+                        "head", n_layers, chapter, time.perf_counter() - t0))
 
-        # negative regeneration (UpdateXNEG)
-        if has_neg:
-            t0 = time.perf_counter()
-            # params travel with scores: only needs_scores strategies see
-            # the live model (key-only regen gets None on the executor's
-            # per-node path too — keep both drivers' contracts identical)
-            scores = None
-            if neg.needs_scores:
-                scores = _class_scores_chunked(params, x_all, cfg)
-            xn0 = ff_mlp._norm(neg.fn(
-                jax.random.fold_in(kneg, chapter), cfg,
-                params if neg.needs_scores else None,
-                x_all, y_all, scores))
-            jax.block_until_ready(xn0)
-            records.append(TaskRecord(
-                "neg_gen", -1, chapter, time.perf_counter() - t0))
+            # negative regeneration (UpdateXNEG)
+            if has_neg:
+                with tracer.span("task:neg_gen", chapter=chapter):
+                    t0 = time.perf_counter()
+                    # params travel with scores: only needs_scores
+                    # strategies see the live model (key-only regen gets
+                    # None on the executor's per-node path too — keep
+                    # both drivers' contracts identical)
+                    scores = None
+                    if neg.needs_scores:
+                        with tracer.span("neg:score", chapter=chapter,
+                                         rows=x_all.shape[0]):
+                            scores = _class_scores_chunked(params, x_all,
+                                                           cfg)
+                    xn0 = ff_mlp._norm(neg.fn(
+                        jax.random.fold_in(kneg, chapter), cfg,
+                        params if neg.needs_scores else None,
+                        x_all, y_all, scores))
+                    with obs_trace.annotate("task:wait"):
+                        jax.block_until_ready(xn0)
+                    records.append(TaskRecord(
+                        "neg_gen", -1, chapter, time.perf_counter() - t0))
 
-        if probe_every and (chapter + 1) % probe_every == 0:
-            acc = ff_mlp.accuracy(params, task.x_test, task.y_test,
-                                  cfg.num_classes, good.eval_mode(cfg),
-                                  impl=impl)
-            history.append((chapter + 1, acc))
-            if verbose:
-                print(f"  chapter {chapter + 1}/{S}: test acc {acc:.4f}")
+            if probe_every and (chapter + 1) % probe_every == 0:
+                with tracer.span("fit:eval", rows=len(task.x_test)):
+                    acc = ff_mlp.accuracy(params, task.x_test, task.y_test,
+                                          cfg.num_classes,
+                                          good.eval_mode(cfg), impl=impl)
+                history.append((chapter + 1, acc))
+                if verbose:
+                    print(f"  chapter {chapter + 1}/{S}: test acc "
+                          f"{acc:.4f}")
 
     mode = good.eval_mode(cfg)
-    test_acc = ff_mlp.accuracy(params, task.x_test, task.y_test,
-                               cfg.num_classes, mode, impl=impl)
-    train_acc = ff_mlp.accuracy(params, task.x_train[:2000],
-                                task.y_train[:2000], cfg.num_classes, mode,
-                                impl=impl)
+    n_eval = min(len(task.x_train), 2000)
+    with tracer.span("fit:eval", rows=len(task.x_test) + n_eval):
+        test_acc = ff_mlp.accuracy(params, task.x_test, task.y_test,
+                                   cfg.num_classes, mode, impl=impl)
+        train_acc = ff_mlp.accuracy(params, task.x_train[:n_eval],
+                                    task.y_train[:n_eval],
+                                    cfg.num_classes, mode, impl=impl)
     return TrainResult(params, records, test_acc, train_acc, cfg, history)
 
 

@@ -94,14 +94,21 @@ retries, reassignments, checkpoint/restore cost and faults injected.
 Observability (``repro.obs``): ``run(trace=...)`` records one
 ``task:<kind>`` span per DAG task (attrs kind/layer/chapter/node,
 and the id of the device its output landed on),
-``handoff:*`` events from the transfer slots, ``resilience:*`` events
-and counters from the retry/checkpoint machinery, and a closing
+``handoff:*`` events from the transfer slots, the ``resilience:retry``
+event and counters from the retry/checkpoint machinery, and a closing
 ``run`` span carrying the DAG shape — everything ``obs.analyze`` needs
 to rebuild the critical path over ``pff_dag.deps`` and attribute
 hand-off cost on/off it. The old ``profile=True`` path now rides the
 tracer: ``ExecResult.records`` / ``node_busy`` are derived from the
 task spans (identical order and semantics), and the untraced default
 pays only no-op tracer calls (``obs.trace.NOOP``).
+
+The MLP executor's spans are lexical, so each is also a profiler
+annotation (traced or not; ``obs.trace``): ``fit:init`` (set-up),
+``chapter`` and ``chapter:inputs``, the task spans with ``task:wait``
+(the timeline mode's block) and ``neg:score`` inside, ``task:handoff``
+(the next layer's inputs) and ``fit:eval`` — the same names the
+sequential trainer (``pff.run_chapter_schedule``) opens.
 """
 from __future__ import annotations
 
@@ -230,9 +237,9 @@ class _Handoff:
                       "corrupt_detected": 0}
 
     def _event(self, name, slot_name, node, version):
-        # every counter bump mirrors onto the tracer timeline, so the
-        # analyzer's on/off-critical-path attribution reconciles with
-        # these stats exactly (a trace-smoke gate)
+        # the prefetch and pull counters mirror onto the tracer
+        # timeline, so the analyzer's on/off-critical-path attribution
+        # reconciles with these stats exactly (a trace-smoke gate)
         if self.tracer.enabled:
             self.tracer.event(name, tree=str(slot_name[0]), node=node,
                               version=version)
@@ -251,7 +258,6 @@ class _Handoff:
             action = self.fault_cb(name, node, version)
             if action == "drop":
                 self.stats["prefetch_dropped"] += 1
-                self._event("handoff:drop", name, node, version)
                 return
             if action == "corrupt":
                 tree = jax.tree_util.tree_map(self._poison, tree)
@@ -277,7 +283,6 @@ class _Handoff:
                 # integrity gate: poisoned bits are never served
                 del self.slots[(name, node)]
                 self.stats["corrupt_detected"] += 1
-                self._event("handoff:corrupt_detected", name, node, version)
             else:
                 if pop:
                     del self.slots[(name, node)]
@@ -412,12 +417,15 @@ class PFFExecutor:
         for node in param_nodes:
             self._handoff.prefetch(("params", k), node, chapter, state[0])
 
-    def _fwd(self, lp, x):
-        """One layer forward + Hinton length-norm — the inter-layer
-        hand-off. ``ff_mlp.fwd_norm`` is the exact call the sequential
-        trainer makes (bit-exactness depends on it); the norm divide
-        runs in the ``ff_dense`` kernel epilogue."""
-        return ff_mlp.fwd_norm(lp, x, impl=self.impl)
+    def _handoff_fwd(self, k, lp, acts):
+        """Layer k forward + Hinton length-norm of every stream — the
+        inter-layer hand-off. ``ff_mlp.fwd_norm`` is the exact call the
+        sequential trainer makes (bit-exactness depends on it); the
+        norm divide runs in the ``ff_dense`` kernel epilogue."""
+        with obs_trace.annotate("task:handoff", layer=k,
+                                rows=sum(a.shape[0] for a in acts)):
+            return tuple(ff_mlp.fwd_norm(lp, a, impl=self.impl)
+                         for a in acts)
 
     def _xn0_for(self, chapter, node):
         """The (full-size, normalized) negatives the sequential trainer
@@ -451,17 +459,23 @@ class PFFExecutor:
         return ((const["xk0"] if idx is None else const["xk0"][idx],),
                 (const["y"] if idx is None else const["y"][idx],))
 
-    def _finish_task(self, node, kind, layer, chapter, t0, out):
-        """Close one DAG task: block (timeline mode only — real device
-        time at the cost of overlap) and record the ``task:<kind>`` span
-        the analyzer's critical path is built from. ``ExecResult.records``
-        / ``node_busy`` are derived from these spans after the run."""
+    def _task_span(self, kind, layer, chapter, node, **attrs):
+        """The ``task:<kind>`` span one DAG task body runs in: the
+        analyzer's critical path is built from these spans, and
+        ``ExecResult.records`` / ``node_busy`` are derived from them
+        after the run."""
+        return self._tracer.span("task:" + kind, kind=kind, layer=layer,
+                                 chapter=chapter, node=node, **attrs)
+
+    def _finish_task(self, span, out):
+        """End one DAG task inside its span: block (timeline mode only
+        — real device time at the cost of overlap) and name the device
+        its output landed on."""
         if self._block:
-            jax.block_until_ready(out)
-        tr = self._tracer
-        if tr.enabled:
-            tr.add_span("task:" + kind, t0, kind=kind, layer=layer,
-                        chapter=chapter, node=node, device=_device_id(out))
+            with obs_trace.annotate("task:wait"):
+                jax.block_until_ready(out)
+        if self._tracer.enabled:
+            span["device"] = _device_id(out)
 
     def _rtime(self, name, dt):
         """Resilience seconds: one mechanism feeds both ``_rstats``
@@ -536,9 +550,6 @@ class PFFExecutor:
         """
         self._dead.add(node)
         self._rstats["dead_nodes"].append(node)
-        if self._tracer.enabled:
-            self._tracer.event("resilience:dead_node", node=node,
-                               schedule=self.schedule)
         if self.schedule == "federated":
             self._rstats["shards_dropped"] += 1
             return
@@ -559,9 +570,6 @@ class PFFExecutor:
             # resume tests exercise real crash recovery. Sync first so
             # the pre-kill checkpoint (phase="post") is really on disk.
             jax.block_until_ready([s[0] for s in self._states])
-            if self._tracer.enabled:
-                self._tracer.event("resilience:kill", chapter=chapter,
-                                   phase=phase)
             print(f"[pff_exec] injected kill at chapter {chapter} "
                   f"({phase})", flush=True)
             os._exit(faults_lib.KILL_EXIT)
@@ -692,61 +700,69 @@ class PFFExecutor:
         previous chapter computed (popped: the jit donates its buffers);
         the outgoing state is immediately published toward its DAG
         consumers."""
-        t0 = self._tracer.now()
-        if self.resilience is not None:
-            # the driver computed acts/extras before this (possibly
-            # retried) dispatch — if the node was reassigned to a
-            # surviving device mid-retry they still live on the dead
-            # one, so re-place them (same-device no-op otherwise)
-            acts = jax.device_put(acts, self.devices[node])
-            extras = jax.device_put(extras, self.devices[node])
-        state = self._handoff.take(("state", k), node, self._ver[k],
-                                   self._states[k], pop=True)
-        state = self.good.train_chapter(
-            state, acts, extras, lrs, jax.random.fold_in(kc, k),
-            cfg=self.cfg, epochs=self.C)
-        self._states[k] = state
-        self._ver[k] = chapter
-        self._prefetch_state(k, chapter, state)
-        if self._publish is not None:
-            # push the freshly-trained layer onto the serving bus the
-            # moment its chapter-train task completes — FF's layer
-            # locality is what makes the mid-run per-layer hot-swap
-            # sound (no global backward pass to invalidate it). The bus
-            # copies before parking; the donated buffers stay ours.
-            self._publish.publish_layer(k, chapter, self.good.export([state]))
-        self._finish_task(node, "train", k, chapter, t0, state[0])
+        steps = ff_mlp._num_batches(acts[0].shape[0],
+                                    self.cfg.batch_size) * self.C
+        with self._task_span("train", k, chapter, node,
+                             steps=steps) as span:
+            if self.resilience is not None:
+                # the driver computed acts/extras before this (possibly
+                # retried) dispatch — if the node was reassigned to a
+                # surviving device mid-retry they still live on the
+                # dead one, so re-place them (same-device no-op
+                # otherwise)
+                acts = jax.device_put(acts, self.devices[node])
+                extras = jax.device_put(extras, self.devices[node])
+            state = self._handoff.take(("state", k), node, self._ver[k],
+                                       self._states[k], pop=True)
+            state = self.good.train_chapter(
+                state, acts, extras, lrs, jax.random.fold_in(kc, k),
+                cfg=self.cfg, epochs=self.C)
+            self._states[k] = state
+            self._ver[k] = chapter
+            self._prefetch_state(k, chapter, state)
+            if self._publish is not None:
+                # push the freshly-trained layer onto the serving bus
+                # the moment its chapter-train task completes — FF's
+                # layer locality is what makes the mid-run per-layer
+                # hot-swap sound (no global backward pass to invalidate
+                # it). The bus copies before parking; the donated
+                # buffers stay ours.
+                self._publish.publish_layer(k, chapter,
+                                            self.good.export([state]))
+            self._finish_task(span, state[0])
         return state[0]
 
     def _head_task_body(self, chapter, node, idx, lrs_head, kc):
         const = self._const[node]
-        t0 = self._tracer.now()
-        xn_all = (const["x_neutral"] if idx is None
-                  else const["x_neutral"][idx])
-        # pull every layer onto the head node (no-op when already there,
-        # e.g. all_layers; prefetched hand-off for single_layer)
-        feats = ff_mlp.softmax_feats(
-            [self._layer_params(k, node)
-             for k in range(self.n_layers)], xn_all, impl=self.impl)
-        head, op = self._handoff.take(("head",), node, self._head_ver,
-                                      self._head, pop=True)
-        head, op = ff_mlp.train_head_chapter(
-            head, op, feats, const["y"] if idx is None else const["y"][idx],
-            lrs_head, jax.random.fold_in(kc, 77),
-            batch=self.cfg.batch_size, epochs=self.C)
-        self._head = (head, op)
-        self._head_ver = chapter
-        if self._publish is not None:
-            self._publish.publish_head(chapter, head)
-        if chapter + 1 < self.cfg.splits:
-            nxt = pff_dag.head_node_of(self.schedule, self.num_nodes,
-                                       n_layers=self.n_layers,
-                                       chapter=chapter + 1)
-            if nxt != node:
-                self._handoff.prefetch(("head",), nxt, chapter,
-                                       (head, op))
-        self._finish_task(node, "head", self.n_layers, chapter, t0,
-                          head["w"])
+        with self._task_span("head", self.n_layers, chapter,
+                             node) as span:
+            xn_all = (const["x_neutral"] if idx is None
+                      else const["x_neutral"][idx])
+            # pull every layer onto the head node (no-op when already
+            # there, e.g. all_layers; prefetched hand-off for
+            # single_layer)
+            feats = ff_mlp.softmax_feats(
+                [self._layer_params(k, node)
+                 for k in range(self.n_layers)], xn_all, impl=self.impl)
+            head, op = self._handoff.take(("head",), node, self._head_ver,
+                                          self._head, pop=True)
+            head, op = ff_mlp.train_head_chapter(
+                head, op, feats,
+                const["y"] if idx is None else const["y"][idx],
+                lrs_head, jax.random.fold_in(kc, 77),
+                batch=self.cfg.batch_size, epochs=self.C)
+            self._head = (head, op)
+            self._head_ver = chapter
+            if self._publish is not None:
+                self._publish.publish_head(chapter, head)
+            if chapter + 1 < self.cfg.splits:
+                nxt = pff_dag.head_node_of(self.schedule, self.num_nodes,
+                                           n_layers=self.n_layers,
+                                           chapter=chapter + 1)
+                if nxt != node:
+                    self._handoff.prefetch(("head",), nxt, chapter,
+                                           (head, op))
+            self._finish_task(span, head["w"])
 
     def _neg_task_body(self, chapter, node):
         """Score-needing (AdaptiveNEG) regeneration from the full
@@ -754,23 +770,28 @@ class PFFExecutor:
         ("UpdateXNEG(publish=True)" — the DAG's strict_neg gating,
         matching the sequential trainer)."""
         const = self._const[node]
-        t0 = self._tracer.now()
-        params = {"layers": [self._layer_params(k, node)
-                             for k in range(self.n_layers)]}
-        scores = pff._class_scores_chunked(params, const["x"], self.cfg)
-        xn0 = ff_mlp._norm(self.neg.fn(
-            jax.random.fold_in(self.kneg, chapter), self.cfg, params,
-            const["x"], const["y"], scores))
-        self._neg = (chapter, xn0)
-        # publish toward every node that trains chapter c+1 while the
-        # current chapter's tail (head task etc.) is still in flight
-        if chapter + 1 < self.cfg.splits:
-            for nxt in pff_dag.chapter_train_nodes(
-                    self.schedule, self.num_nodes, self.n_layers,
-                    chapter=chapter + 1):
-                if nxt != node:
-                    self._handoff.prefetch(("neg",), nxt, chapter, xn0)
-        self._finish_task(node, "neg_gen", -1, chapter, t0, xn0)
+        with self._task_span("neg_gen", -1, chapter, node) as span:
+            params = {"layers": [self._layer_params(k, node)
+                                 for k in range(self.n_layers)]}
+            with self._tracer.span("neg:score", chapter=chapter,
+                                   rows=const["x"].shape[0]):
+                scores = pff._class_scores_chunked(params, const["x"],
+                                                   self.cfg)
+            xn0 = ff_mlp._norm(self.neg.fn(
+                jax.random.fold_in(self.kneg, chapter), self.cfg, params,
+                const["x"], const["y"], scores))
+            self._neg = (chapter, xn0)
+            # publish toward every node that trains chapter c+1 while
+            # the current chapter's tail (head task etc.) is still in
+            # flight
+            if chapter + 1 < self.cfg.splits:
+                for nxt in pff_dag.chapter_train_nodes(
+                        self.schedule, self.num_nodes, self.n_layers,
+                        chapter=chapter + 1):
+                    if nxt != node:
+                        self._handoff.prefetch(("neg",), nxt, chapter,
+                                               xn0)
+            self._finish_task(span, xn0)
 
     # ---- schedule drivers ------------------------------------------------
     def _run_chapter_owned(self, chapter):
@@ -779,9 +800,10 @@ class PFFExecutor:
         node = pff_dag.node_of(self.schedule, self.num_nodes, layer=0,
                                chapter=chapter)
         idx = self._const[node]["idx"]
-        lrs, lrs_head = self._lrs(chapter)
-        kc = jax.random.fold_in(self.key, chapter)
-        acts, extras = self._chapter_inputs(chapter, node)
+        with self._tracer.span("chapter:inputs", chapter=chapter):
+            lrs, lrs_head = self._lrs(chapter)
+            kc = jax.random.fold_in(self.key, chapter)
+            acts, extras = self._chapter_inputs(chapter, node)
         for k in range(self.n_layers):
             lp = self._train_task(k, chapter, node, acts, extras, lrs,
                                   kc)
@@ -791,7 +813,7 @@ class PFFExecutor:
                     # on the dead node's device while lp lands on the
                     # surviving one — re-place (same-device no-op)
                     acts = jax.device_put(acts, self.devices[node])
-                acts = tuple(self._fwd(lp, a) for a in acts)
+                acts = self._handoff_fwd(k, lp, acts)
         if self.has_head:
             self._head_task(chapter, node, idx, lrs_head, kc)
         if self.has_neg and self.neg.needs_scores:
@@ -802,15 +824,17 @@ class PFFExecutor:
         pass of layers < k over the train set (Algorithm 1 lines 3-5) —
         the load imbalance the paper observes. Weight hand-off: node k
         pulls layers 0..k-1's chapter-c weights as they appear."""
-        lrs, lrs_head = self._lrs(chapter)
-        kc = jax.random.fold_in(self.key, chapter)
+        with self._tracer.span("chapter:inputs", chapter=chapter):
+            lrs, lrs_head = self._lrs(chapter)
+            kc = jax.random.fold_in(self.key, chapter)
         for k in range(self.n_layers):
             node = pff_dag.node_of(self.schedule, self.num_nodes,
                                    layer=k, chapter=chapter)
-            acts, extras = self._chapter_inputs(chapter, node)
+            with self._tracer.span("chapter:inputs", chapter=chapter):
+                acts, extras = self._chapter_inputs(chapter, node)
             for j in range(k):       # Algorithm-1 forward recompute
-                w_j = self._layer_params(j, node)
-                acts = tuple(self._fwd(w_j, a) for a in acts)
+                acts = self._handoff_fwd(j, self._layer_params(j, node),
+                                         acts)
             self._train_task(k, chapter, node, acts, extras, lrs, kc)
         if self.has_head:
             node = pff_dag.head_node_of(self.schedule, self.num_nodes,
@@ -870,16 +894,16 @@ class PFFExecutor:
 
             def body(node=node, const=const, idx=idx, acts=acts,
                      extras=extras, st0=st0, head0=head0):
-                t0 = self._tracer.now()
-                out = pff.elastic_node_round(
-                    self.good, self.cfg, st0, head0, acts, extras, lrs,
-                    lrs_head, jax.random.fold_in(kr, node),
-                    epochs=self.C, impl=self.impl,
-                    y=const["y"][idx] if self.has_head else None,
-                    x_neutral=(const["x_neutral"][idx]
-                               if self.has_head else None),
-                    train_head=self.has_head)
-                self._finish_task(node, "round", -1, r, t0, out[0][0][0])
+                with self._task_span("round", -1, r, node) as span:
+                    out = pff.elastic_node_round(
+                        self.good, self.cfg, st0, head0, acts, extras,
+                        lrs, lrs_head, jax.random.fold_in(kr, node),
+                        epochs=self.C, impl=self.impl,
+                        y=const["y"][idx] if self.has_head else None,
+                        x_neutral=(const["x_neutral"][idx]
+                                   if self.has_head else None),
+                        train_head=self.has_head)
+                    self._finish_task(span, out[0][0][0])
                 return out
 
             try:
@@ -984,16 +1008,17 @@ class PFFExecutor:
         # undo a previous run's dead-node remapping (benchmarks reuse
         # the executor for warm-cache timing)
         self.devices[:] = self._devices_init
-        if self._const_dirty:
-            self._setup_constants()
-            self._const_dirty = False
+        with tracer.span("fit:init"):
+            if self._const_dirty:
+                self._setup_constants()
+                self._const_dirty = False
+            params = ff_mlp.init(jax.random.PRNGKey(cfg.seed), cfg)
+            opt = ff_mlp.opt_init(params)
         self._dead: set = set()
         self._rstats = self._fresh_rstats()
         elastic = rc is not None and rc.membership is not None
         if elastic:
             self._rstats["elastic_rounds"] = []
-        params = ff_mlp.init(jax.random.PRNGKey(cfg.seed), cfg)
-        opt = ff_mlp.opt_init(params)
         self._neg: Tuple[int, object] = (-1, None)
         self._ver = [-1] * self.n_layers       # chapter of last train(k)
         self._head_ver = -1
@@ -1027,37 +1052,38 @@ class PFFExecutor:
         self._publish_snapshot(min([self._head_ver] + self._ver
                                    if self.has_head else self._ver))
         for chapter in range(start_chapter, cfg.splits):
-            if elastic:
-                self._run_round_elastic(chapter)
-            elif self.schedule == "single_layer":
-                self._run_chapter_single_layer(chapter)
-            elif (self.schedule == "federated" and self._dead
-                  and pff_dag.node_of(self.schedule, self.num_nodes,
-                                      layer=0, chapter=chapter)
-                  in self._dead):
-                # the owning node died in an earlier chapter — its shard
-                # no longer contributes (the model rests this round)
-                self._rstats["chapters_skipped"] += 1
-            elif self.schedule == "federated" and plan is not None:
-                # a mid-chapter death must not leave a half-trained
-                # chapter: snapshot the recovery line (copies — the
-                # chapter trainers donate the live buffers) and roll
-                # back on _ShardDropped
-                snap = jax.tree_util.tree_map(
-                    jnp.copy, (list(self._states), self._head))
-                snap_meta = (list(self._ver), self._head_ver, self._neg)
-                try:
-                    self._run_chapter_owned(chapter)
-                except _ShardDropped:
-                    self._states, self._head = list(snap[0]), snap[1]
-                    self._ver, self._head_ver, self._neg = (
-                        list(snap_meta[0]), snap_meta[1], snap_meta[2])
+            with tracer.span("chapter", chapter=chapter):
+                if elastic:
+                    self._run_round_elastic(chapter)
+                elif self.schedule == "single_layer":
+                    self._run_chapter_single_layer(chapter)
+                elif (self.schedule == "federated" and self._dead
+                      and pff_dag.node_of(self.schedule, self.num_nodes,
+                                          layer=0, chapter=chapter)
+                      in self._dead):
+                    # the owning node died in an earlier chapter — its shard
+                    # no longer contributes (the model rests this round)
                     self._rstats["chapters_skipped"] += 1
-            else:
-                self._run_chapter_owned(chapter)
-            self._write_checkpoint(chapter)
-            if rc is not None:
-                self._maybe_kill(chapter, "post")
+                elif self.schedule == "federated" and plan is not None:
+                    # a mid-chapter death must not leave a half-trained
+                    # chapter: snapshot the recovery line (copies — the
+                    # chapter trainers donate the live buffers) and roll
+                    # back on _ShardDropped
+                    snap = jax.tree_util.tree_map(
+                        jnp.copy, (list(self._states), self._head))
+                    snap_meta = (list(self._ver), self._head_ver, self._neg)
+                    try:
+                        self._run_chapter_owned(chapter)
+                    except _ShardDropped:
+                        self._states, self._head = list(snap[0]), snap[1]
+                        self._ver, self._head_ver, self._neg = (
+                            list(snap_meta[0]), snap_meta[1], snap_meta[2])
+                        self._rstats["chapters_skipped"] += 1
+                else:
+                    self._run_chapter_owned(chapter)
+                self._write_checkpoint(chapter)
+                if rc is not None:
+                    self._maybe_kill(chapter, "post")
         outs = [s[0] for s in self._states] + [self._head[0]]
         if self._neg[1] is not None:
             outs.append(self._neg[1])
@@ -1076,11 +1102,12 @@ class PFFExecutor:
                 start_chapter=start_chapter, overlap=self.overlap,
                 blocked=self._block, makespan_s=makespan)
 
-        final = self._pull({**self.good.export(self._states),
-                            "head": self._head[0]}, 0)
-        acc = ff_mlp.accuracy(final, self.task.x_test, self.task.y_test,
-                              cfg.num_classes, self.good.eval_mode(cfg),
-                              impl=self.impl)
+        with tracer.span("fit:eval", rows=len(self.task.x_test)):
+            final = self._pull({**self.good.export(self._states),
+                                "head": self._head[0]}, 0)
+            acc = ff_mlp.accuracy(final, self.task.x_test, self.task.y_test,
+                                  cfg.num_classes, self.good.eval_mode(cfg),
+                                  impl=self.impl)
         records = node_busy = None
         if timeline:
             records, node_busy = _records_from_spans(tracer, span0,

@@ -326,7 +326,7 @@ def tune_ff_dense(shapes, *, norms=(False, True), dtype=jnp.float32,
     (smoke mode). ``save=True`` writes the table and drops the memo so
     subsequent ``lookup``s see the new winners. ``tracer=`` (an
     ``obs.trace`` tracer) records one ``tune:candidate`` span per
-    measured candidate and a ``tune:reject`` event per gate breach.
+    measured candidate.
     """
     platform = jax.default_backend()
     interpret = platform != "tpu"
@@ -377,9 +377,6 @@ def tune_ff_dense(shapes, *, norms=(False, True), dtype=jnp.float32,
                 except Exception as e:  # an impl that cannot even run
                     rejected.append({"impl": name, "blocks": blocks,
                                      "reason": f"raised {e!r}"})
-                    if tracer.enabled:
-                        tracer.event("tune:reject", key=key, impl=name,
-                                     reason="raised")
                     continue
                 if err > err_gate or grad_err > err_gate:
                     rejected.append({
@@ -387,10 +384,6 @@ def tune_ff_dense(shapes, *, norms=(False, True), dtype=jnp.float32,
                         "reason": (f"oracle error breach: err={err:.2e} "
                                    f"grad_err={grad_err:.2e} > "
                                    f"{err_gate:.0e}")})
-                    if tracer.enabled:
-                        tracer.event("tune:reject", key=key, impl=name,
-                                     reason="oracle_error", err=err,
-                                     grad_err=grad_err)
                     continue
                 step = jax.jit(jax.value_and_grad(
                     _make_loss(registry_lib.ff_dense.get(name).fn,

@@ -3,7 +3,9 @@
 The measurement substrate under every wall-clock claim in the repo:
 
 * ``obs.trace`` — thread-safe ``Tracer`` (spans / events / counters on
-  one monotonic clock domain) with a zero-overhead ``NOOP`` default.
+  one monotonic clock domain) with a ``NOOP`` default that records
+  nothing; every lexical span, the ``NOOP``'s too, is also a
+  ``jax.profiler.TraceAnnotation`` on the device trace's clock.
 * ``obs.export`` — exporter registry (``register_exporter``) with
   Chrome/Perfetto ``trace.json`` and JSONL builtins.
 * ``obs.analyze`` — critical path over ``pff_dag.deps``, per-node

@@ -7,21 +7,39 @@ thread all land on a single comparable timeline. Producers record:
 
 * ``span(name, **attrs)`` — a context manager for a timed region, or
   the manual ``add_span(name, t0, t1=None, **attrs)`` when the region
-  does not nest lexically (the executor opens a task span before an
-  async JAX dispatch and closes it after ``block_until_ready``).
+  does not nest lexically (recorded after the fact).
 * ``event(name, **attrs)`` — an instantaneous marker (prefetch hit,
-  retry, shed, version-vector violation, ...).
+  retry, ...).
 * ``counter(name, value)`` — an accumulating scalar (checkpoint /
   restore / recovery seconds, folding the executor's scattered
   resilience timers onto the tracer).
 
+Every lexical ``span`` — on a ``Tracer`` and on the disabled ``NOOP``
+alike — is also a ``jax.profiler.TraceAnnotation`` for its extent, so
+it shows on the host thread's line of any profiler trace
+(``jax.profiler.trace(dir)``, the benchmark's ``--trace 1``) on the
+device trace's own clock: the device's idle gaps can be attributed to
+the span the host was in without offset arithmetic. ``annotate(name,
+**attrs)`` opens the annotation alone, for sub-task regions that must
+not become records (``task:wait``, ``task:handoff``: the recorded
+``task:*`` spans are DAG tasks, which ``obs.analyze`` and the
+executor's records read). ``add_span`` runs after the region has
+ended, so its spans never reach the profiler: the serve loop, the
+checkpoint writer, the autotuner and the executor's closing ``run``
+span stay in the in-memory trace only.
+
+Attributes are Python ints and strings: a span never reads a device
+value or blocks, since that would add a sync the untraced run does not
+have. ``jax.profiler`` is imported on the first span, not here.
+
 The default tracer is the module-level ``NOOP`` singleton: every hot
-path in the repo calls through it unconditionally, and its methods are
-constant-time attribute hits that allocate nothing, so an untraced run
-pays only a few ``enabled``-flag checks (the ``<2%`` overhead gate in
-``benchmarks/trace.py`` measures exactly this). Producers that would
-do real work just to *build* a span (formatting attrs, snapshotting
-queue depths) must guard on ``tracer.enabled`` first.
+path in the repo calls through it unconditionally. It records nothing;
+its spans cost one profiler annotation (about a microsecond with no
+profiler session running) and its other methods are constant-time
+attribute hits, so an untraced run pays little (the ``<2%`` overhead
+gate in ``benchmarks/trace.py`` measures exactly this). Producers that
+would do real work just to *build* a record (formatting attrs,
+snapshotting queue depths) must guard on ``tracer.enabled`` first.
 
 ``block_tasks`` is the JAX-async knob: with it (the default) the
 executor calls ``jax.block_until_ready`` before closing each task
@@ -32,17 +50,30 @@ dispatch only; ``benchmarks/trace.py`` therefore uses a two-run
 protocol (traced+blocked run for the timeline, untraced warm run for
 the makespan) mirroring ``benchmarks/pff_exec.py``.
 
-This module imports nothing from the rest of the repo (and no jax), so
-``checkpoint.py`` and every ``core``/``serve`` module can depend on it
-without import cycles.
+This module imports nothing from the rest of the repo (and no jax at
+import time), so ``checkpoint.py`` and every ``core``/``serve`` module
+can depend on it without import cycles.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+
+@functools.cache
+def _trace_annotation():
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
+
+
+def annotate(name: str, **attrs):
+    """A profiler annotation over a ``with`` block, recorded by no
+    tracer: the span shows in a profiler trace only."""
+    return _trace_annotation()(name, **attrs)
 
 
 @dataclasses.dataclass
@@ -107,11 +138,16 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
-        t0 = self.now()
-        try:
-            yield self
-        finally:
-            self.add_span(name, t0, **attrs)
+        """Record the ``with`` block as a span, also annotated in any
+        running profiler session. Yields the span's attrs: the block
+        may add to them before the span closes (the annotation carries
+        only those given here)."""
+        with annotate(name, **attrs):
+            t0 = self.now()
+            try:
+                yield attrs
+            finally:
+                self.add_span(name, t0, **attrs)
 
     def event(self, name: str, **attrs) -> Event:
         ev = Event(name, self.now(), threading.current_thread().name, attrs)
@@ -148,11 +184,10 @@ class Tracer:
 
 
 class _NoopTracer:
-    """Shared disabled tracer: the zero-overhead default.
+    """Shared disabled tracer: the default.
 
-    Records nothing; every method is a cheap constant. ``span()``
-    returns one reusable null context manager (no allocation per
-    call).
+    Records nothing; ``span()`` is a profiler annotation alone and
+    every other method is a cheap constant.
     """
 
     enabled = False
@@ -162,17 +197,16 @@ class _NoopTracer:
     events: List[Event] = []
     counters: Dict[str, float] = {}
 
-    def __init__(self):
-        self._null_cm = contextlib.nullcontext(self)
-
     def now(self) -> float:
         return 0.0
 
     def add_span(self, name, t0, t1=None, **attrs):
         return None
 
+    @contextlib.contextmanager
     def span(self, name, **attrs):
-        return self._null_cm
+        with annotate(name, **attrs):
+            yield attrs
 
     def event(self, name, **attrs):
         return None

@@ -14,8 +14,8 @@ machinery behind it.
 
 Observability: every entry point takes ``tracer=`` (an ``obs.trace``
 tracer, default the no-op singleton). The loop records admission /
-batch-form / score spans and shed events; the replica records
-swap-install spans and violation events on the SAME tracer. In
+batch-form / score spans; the replica records swap-install spans on
+the SAME tracer. In
 combined mode ``train_while_serve`` hands that one tracer to the
 executor thread too, so training task spans and serving spans share a
 single clock domain — the whole train-while-serve run is one Perfetto
@@ -105,10 +105,9 @@ def run_serve(replica: Replica, bus: WeightBus, stream: RequestStream,
     if producer_done is None and n_target is None:
         raise ValueError("serve-only mode needs ServeConfig.n_requests")
     if tracer.enabled:
-        # swap-install spans / violation events land on the loop's
+        # swap-install spans land on the loop's
         # tracer (one clock domain with the executor in combined mode)
         replica.tracer = tracer
-    t_loop0 = tracer.now()
     queue = AdmissionQueue(sconfig.queue_cap)
     batcher = Batcher(sconfig.max_batch, sconfig.max_wait_s)
     done: List[Request] = []
@@ -146,9 +145,7 @@ def run_serve(replica: Replica, bus: WeightBus, stream: RequestStream,
             if draining:
                 req.t_arrival = t
             req.t_admit = t
-            if not queue.offer(req) and tracer.enabled:
-                tracer.event("serve:shed", id=req.id,
-                             depth=len(queue))
+            queue.offer(req)
             admitted += 1
             refill()
         if tracer.enabled and admitted > n_before:
@@ -193,10 +190,6 @@ def run_serve(replica: Replica, bus: WeightBus, stream: RequestStream,
         time.sleep(_IDLE_SLEEP_S)
 
     replica.drain(bus, now=now())
-    if tracer.enabled:
-        tracer.add_span("serve:loop", t_loop0, requests=len(done),
-                        swaps=len(replica.swaps),
-                        violations=replica.consistency_violations)
     return EngineResult(
         requests=done, swaps=list(replica.swaps),
         consistency_violations=replica.consistency_violations,

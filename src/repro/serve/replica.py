@@ -65,9 +65,6 @@ class Replica:
         t0 = self.tracer.now()
         if not self._vector_ok(version, vec):
             self.consistency_violations += 1
-            if self.tracer.enabled:
-                self.tracer.event("serve:violation", version=version,
-                                  vec=list(vec), installed=self.version)
             return False
         self.params = params
         old = self.version

@@ -10,6 +10,7 @@ happens in ONE subprocess — ``python -m repro.obs.analyze`` — because
 conftest keeps the in-process runner on a single CPU device. The
 in-process executor tests hand the same device to N logical nodes.
 """
+import collections
 import json
 import os
 import subprocess
@@ -22,8 +23,18 @@ from repro.obs import analyze as analyze_lib
 from repro.obs import export as export_lib
 from repro.obs import trace as trace_lib
 
-_SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                    os.pardir, "src")
+_ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                     os.pardir)
+_SRC = os.path.join(_ROOT, "src")
+
+
+def _profiled(log_dir):
+    """A profiler session with the benchmark's options (no Python
+    tracer)."""
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return jax.profiler.trace(str(log_dir), profiler_options=opts)
 
 
 # ---------------------------------------------------------------------------
@@ -93,13 +104,18 @@ def test_thread_safety_hammer():
                                                  for i in range(n_threads)}
 
 
-def test_noop_is_inert_and_allocation_free():
+def test_noop_is_inert_and_allocation_free(tmp_path):
+    from bench import spans
     noop = trace_lib.NOOP
     assert not noop.enabled
     with noop.span("x", a=1) as got:
-        assert got is noop
-    # one shared null context manager — no per-call allocation
-    assert noop.span("a") is noop.span("b")
+        assert got == {"a": 1}
+    # a NOOP span records nothing, but it is a profiler annotation
+    with _profiled(tmp_path):
+        with noop.span("task:train", layer=0):
+            pass
+    assert [e.name for e in spans.program_spans(str(tmp_path))] \
+        == ["task:train"]
     assert noop.add_span("x", 0.0) is None
     assert noop.event("x") is None
     assert noop.counter("x", 1.0) is None
@@ -107,6 +123,30 @@ def test_noop_is_inert_and_allocation_free():
     assert noop.snapshot() == []
     assert noop.to_dict() == {"meta": {}, "spans": [], "events": [],
                               "counters": {}}
+
+
+def test_span_yields_attrs_that_the_block_may_extend():
+    tr = trace_lib.Tracer()
+    with tr.span("task:train", layer=0) as attrs:
+        attrs["device"] = 3
+    assert tr.snapshot()[0].attrs == {"layer": 0, "device": 3}
+
+
+def test_obs_imports_jax_profiler_on_the_first_span_only():
+    r = subprocess.run(
+        [sys.executable, "-c",
+         "import sys; from repro.obs import trace; "
+         "assert 'jax' not in sys.modules; "
+         "trace.Tracer(); trace.NOOP.event('e'); "
+         "assert 'jax' not in sys.modules; "
+         "trace.NOOP.span('s').__enter__(); "
+         "assert 'jax.profiler' in sys.modules"],
+        capture_output=True, text=True,
+        env={**os.environ,
+             "PYTHONPATH": _SRC + os.pathsep
+             + os.environ.get("PYTHONPATH", "")},
+        timeout=120)
+    assert r.returncode == 0, r.stderr
 
 
 def test_as_tracer_normalization():
@@ -463,3 +503,139 @@ def test_train_cli_trace_flag(tmp_path):
          "--trace", str(out), "--trace-format", "bogus"],
         capture_output=True, text=True, env=_sub_env(), timeout=120)
     assert r.returncode == 2 and "invalid choice" in r.stderr
+
+
+# ---------------------------------------------------------------------------
+# Program spans in a profiler trace, on the device's clock
+# ---------------------------------------------------------------------------
+
+ADAPTIVE, RANDOM = "mnist_adaptive.seq_1chip", "mnist_random.seq_1chip"
+
+
+def _tiny_job(name):
+    """(cfg, task, model, traffic) of a benchmark cell at ``bench.tiny``
+    size: 784-64x4, 4 chapters of 10 mini-epochs, 256 train rows."""
+    from bench.tiny import tiny
+    from repro import data as data_lib
+    from repro.configs.ff_mlp import FFMLPConfig
+    cell = tiny(name)
+    m = cell.model
+    cfg = FFMLPConfig(**{**m, "layer_sizes": tuple(m["layer_sizes"])},
+                      seed=2 ** 31 + 7)
+    task = data_lib.mnist_like(n_train=cell.traffic["n_train"],
+                               n_test=cell.traffic["n_test"])
+    return cfg, task, m, cell.traffic
+
+
+def _span_tree(prog):
+    """Count of (program span, innermost program span around it)."""
+    tree = collections.Counter()
+    for e in prog:
+        around = [p for p in prog if p is not e and p.start_ns <= e.start_ns
+                  and e.end_ns <= p.end_ns]
+        tree[e.name, min(around, key=lambda p: p.dur_ns).name
+             if around else None] += 1
+    return tree
+
+
+def _expected_tree(fit, L, S, *, scores, handoff_in, waits):
+    tree = collections.Counter({
+        (fit, None): 1, ("fit:eval", fit): 1, ("chapter", fit): S,
+        ("chapter:inputs", "chapter"): S, ("task:train", "chapter"): L * S,
+        ("task:handoff", handoff_in): (L - 1) * S})
+    tree["fit:init", fit] = 1 if fit == "fit:sequential" else 2
+    if waits:
+        tree["task:wait", "task:train"] = L * S
+    if fit == "fit:sequential" or scores:
+        tree["task:neg_gen", "chapter"] = S
+        if waits:
+            tree["task:wait", "task:neg_gen"] = S
+    if scores:
+        tree["neg:score", "task:neg_gen"] = S
+    return tree
+
+
+@pytest.mark.parametrize("name", [ADAPTIVE, RANDOM])
+def test_sequential_spans_in_a_profiler_trace(name, tmp_path):
+    from bench import spans
+    from repro import api
+    cfg, task, _, _ = _tiny_job(name)
+    api.fit(cfg, task)                          # compile outside the trace
+    with _profiled(tmp_path):
+        api.fit(cfg, task)
+    got = _span_tree(spans.program_spans(str(tmp_path)))
+    assert got == _expected_tree(
+        "fit:sequential", L=4, S=4, scores=name == ADAPTIVE,
+        handoff_in="task:train", waits=True)
+
+
+_EXEC_SPANS = """
+import json, sys, tempfile
+import jax
+from bench import spans
+from repro import api, obs
+sys.path.insert(0, "tests")
+from test_obs import _profiled, _tiny_job
+out = {}
+for name, trace in (("%s", obs.Tracer()), ("%s", None)):
+    cfg, task, _, _ = _tiny_job(name)
+    kw = dict(backend="executor", schedule="all_layers", num_nodes=4,
+              devices=jax.devices()[:4])
+    api.fit(cfg, task, **kw)
+    d = tempfile.mkdtemp()
+    with _profiled(d):
+        api.fit(cfg, task, trace=trace, **kw)
+    out[name] = [(e.name, e.start_ns, e.dur_ns)
+                 for e in spans.program_spans(d)]
+print(json.dumps(out))
+""" % (ADAPTIVE, RANDOM)
+
+
+def test_executor_spans_in_a_profiler_trace_n4_subprocess():
+    from bench import tracefile
+    r = subprocess.run([sys.executable, "-c", _EXEC_SPANS], cwd=_ROOT,
+                       capture_output=True, text=True, env=_sub_env(),
+                       timeout=540)
+    assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
+    runs = json.loads(r.stdout.strip().splitlines()[-1])
+    for name, blocking in ((ADAPTIVE, True), (RANDOM, False)):
+        got = _span_tree([tracefile.Event(*e) for e in runs[name]])
+        assert got == _expected_tree(
+            "fit:executor", L=4, S=4, scores=name == ADAPTIVE,
+            handoff_in="chapter", waits=blocking), name
+
+
+@pytest.mark.parametrize("backend", ["sequential", "executor"])
+def test_span_attrs_count_the_jobs_work(backend):
+    """The steps of the task:train spans are the layer steps the
+    benchmark counts, the rows of neg:score its AdaptiveNEG scoring
+    rows."""
+    import jax
+    from bench import flops
+    from repro import api
+    cfg, task, model, traffic = _tiny_job(ADAPTIVE)
+    kw = {}
+    if backend == "executor":
+        kw = dict(schedule="all_layers", num_nodes=4,
+                  devices=[jax.devices()[0]] * 4)
+    tr = trace_lib.Tracer(block_tasks=False)
+    api.fit(cfg, task, backend=backend, trace=tr, **kw)
+    got = collections.Counter()
+    for s in tr.snapshot():
+        got[s.name] += s.attrs.get("steps", s.attrs.get("rows", 0))
+    assert got["task:train"] == flops.layer_steps_per_job(model, traffic)
+    assert got["neg:score"] == sum(
+        c.M // model["num_classes"] * c.count
+        for c in flops.job_kernel_calls(model, traffic)
+        if c.what == "AdaptiveNEG scoring"
+        and c.K == model["layer_sizes"][0])
+
+
+def test_profiler_session_leaves_the_weight_stream_bit_identical(tmp_path):
+    from repro import api
+    from repro.core import pff_exec
+    cfg, task, _, _ = _tiny_job(ADAPTIVE)
+    plain = api.fit(cfg, task)
+    with _profiled(tmp_path):
+        profiled = api.fit(cfg, task)
+    assert pff_exec.params_bit_equal(plain.params, profiled.params)
