@@ -40,11 +40,12 @@ def ff_dense_roofline(M, K, N, *, device_kind, dtype_bytes=4):
         raise KeyError(f"no peak rates for device_kind {device_kind!r}; "
                        f"known: {sorted(PEAKS)}")
     # fwd: matmul 2MKN + bias/relu/square-accumulate ~3MN
-    # bwd: dy rebuild ~4MN + three products (dx, dw via 2MKN each)
-    flops = 3 * (2 * M * K * N) + 7 * M * N
-    # fused-path HBM traffic: x, w, b in; y, g out (fwd) + y, cots in;
-    # dx, dw, db out (bwd) — activations never round-trip inside a step
-    bytes_ = dtype_bytes * (3 * (M * K + K * N) + 3 * M * N
+    # bwd: dy rebuild ~4MN + the dw product 2MKN (the step differentiates
+    # w only, so the dx kernel is dead code)
+    flops = 2 * (2 * M * K * N) + 7 * M * N
+    # fused-path HBM traffic: x, w, b in; y, g out (fwd) + x, y, cots in;
+    # dw, db out (bwd) — activations never round-trip inside a step
+    bytes_ = dtype_bytes * (2 * (M * K + K * N) + 3 * M * N
                             + 2 * N + 3 * M)
     peak_f, peak_b = PEAKS[device_kind]
     t_compute = flops / peak_f

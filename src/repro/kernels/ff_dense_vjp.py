@@ -2,8 +2,8 @@
 
 Forward is the existing fused matmul -> ReLU -> goodness kernel
 (``ff_dense.py``); this module adds the missing piece that makes it the
-*training-time* engine rather than a benchmark curiosity: a fused Pallas
-backward kernel, so ``jax.grad`` of the FF objective runs entirely on
+*training-time* engine rather than a benchmark curiosity: fused Pallas
+backward kernels, so ``jax.grad`` of the FF objective runs entirely on
 the fused path.
 
 Math. With y = relu(x @ w + b) and g = sum(y^2, axis=-1), the cotangents
@@ -15,15 +15,29 @@ Math. With y = relu(x @ w + b) and g = sum(y^2, axis=-1), the cotangents
 
     dw = x^T @ dy      db = sum_rows(dy)      dx = dy @ w^T.
 
-The backward kernel fuses the dy construction with all three products so
-the (M, N) dy never makes an HBM round-trip: grid (K/bk, M/bm) with M
-innermost, dy rebuilt per K-block from the resident y/dy_out/dg row
-blocks (cheap VPU work traded for the HBM traffic of materializing dy).
-dw accumulates across the inner M steps into the same resident (bk, N)
-block; db accumulates on the kb == 0 passes. N is streamed whole per
-block (padded to a lane multiple) — for the paper's 2000-wide layers a
-(128, 2048) f32 block is ~1 MB.
+The backward is two ``pallas_call``s, each rebuilding dy per block from
+the y/dy_out/dg row blocks (cheap VPU work traded for the HBM traffic
+of materializing the (M, N) dy):
 
+* ``_dw_kernel`` (x, y, dy_out, dg) -> (dw, db). Grid (K/bk, M/bm) with
+  M innermost: dw accumulates across the inner M steps into the same
+  resident (bk, N) block; db accumulates on the kb == 0 passes. VMEM
+  blocks: x (bm, bk), y and dy_out (bm, N), dg (bm,), dw (bk, N),
+  db (N,). It never reads w.
+* ``_dx_kernel`` (w, y, dy_out, dg) -> dx. Same grid; each step writes
+  its own (bm, bk) dx block from the (bk, N) w block, which stays
+  resident across the inner M steps. VMEM blocks: w (bk, N), y and
+  dy_out (bm, N), dg (bm,), dx (bm, bk).
+
+dx is a call of its own because no FF objective uses it: every FF loss
+is layer-local and the layer's input is data, so the trainers
+differentiate the parameters only. XLA then removes the dx call, and
+the w pad that only it reads, as dead code, and a trainer's step runs
+the dw/db products alone — half the backward's matmul work. A caller
+that differentiates x keeps the dx call; nothing else selects it.
+
+N is streamed whole per block (padded to a lane multiple) — for the
+paper's 2000-wide layers a (128, 2048) f32 block is ~1 MB.
 Non-tile-aligned shapes are zero-padded exactly like the forward kernel;
 zero rows/cols of x/w/y/dy contribute zero to every product, so slicing
 the outputs back is exact.
@@ -41,17 +55,17 @@ from repro.kernels.ff_dense import (
 )
 
 
-def _bwd_kernel(x_ref, w_ref, y_ref, dyo_ref, dg_ref,
-                dx_ref, dw_ref, db_ref):
-    kb = pl.program_id(0)
-    i = pl.program_id(1)
+def _dy(y_ref, dyo_ref, dg_ref):
+    """The post-activation gradient of one (bm, N) row block."""
     y = y_ref[...].astype(jnp.float32)
     dy = dyo_ref[...].astype(jnp.float32) + 2.0 * y * dg_ref[...][:, None]
-    dy = jnp.where(y > 0.0, dy, 0.0)                      # (bm, N)
+    return jnp.where(y > 0.0, dy, 0.0)
 
-    dx_ref[...] = jnp.dot(
-        dy, w_ref[...].astype(jnp.float32).T, precision=MATMUL_PRECISION,
-        preferred_element_type=jnp.float32).astype(dx_ref.dtype)
+
+def _dw_kernel(x_ref, y_ref, dyo_ref, dg_ref, dw_ref, db_ref):
+    kb = pl.program_id(0)
+    i = pl.program_id(1)
+    dy = _dy(y_ref, dyo_ref, dg_ref)                      # (bm, N)
 
     dw_part = jnp.dot(x_ref[...].astype(jnp.float32).T, dy,
                       precision=MATMUL_PRECISION,
@@ -76,9 +90,19 @@ def _bwd_kernel(x_ref, w_ref, y_ref, dyo_ref, dg_ref,
         db_ref[...] = db_ref[...] + db_part
 
 
+def _dx_kernel(w_ref, y_ref, dyo_ref, dg_ref, dx_ref):
+    dy = _dy(y_ref, dyo_ref, dg_ref)
+    dx_ref[...] = jnp.dot(
+        dy, w_ref[...].astype(jnp.float32).T, precision=MATMUL_PRECISION,
+        preferred_element_type=jnp.float32).astype(dx_ref.dtype)
+
+
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "interpret"))
 def ff_dense_bwd(x, w, y, dy_out, dg, *, bm=128, bk=256, interpret=True):
-    """Fused backward: (x, w, y, dL/dy, dL/dg) -> (dx, dw, db)."""
+    """Fused backward: (x, w, y, dL/dy, dL/dg) -> (dx, dw, db).
+
+    dx comes from a separate kernel, so a caller that drops it (every
+    FF trainer) compiles to the dw/db kernel alone."""
     M, K = x.shape
     N = w.shape[1]
     bm = min(bm, M)
@@ -88,34 +112,40 @@ def ff_dense_bwd(x, w, y, dy_out, dg, *, bm=128, bk=256, interpret=True):
     Np = -(-N // 128) * 128
     if Mp != M or Kp != K or Np != N:
         x = jnp.pad(x, ((0, Mp - M), (0, Kp - K)))
-        w = jnp.pad(w, ((0, Kp - K), (0, Np - N)))
         y = jnp.pad(y, ((0, Mp - M), (0, Np - N)))
         dy_out = jnp.pad(dy_out, ((0, Mp - M), (0, Np - N)))
         dg = jnp.pad(dg, (0, Mp - M))
 
-    grid = (Kp // bk, Mp // bm)          # M innermost: dw stays resident
-    dx, dw, db = pl.pallas_call(
-        _bwd_kernel,
+    grid = (Kp // bk, Mp // bm)          # M innermost: dw, w stay resident
+    rows = [
+        pl.BlockSpec((bm, Np), lambda kb, i: (i, 0)),    # y
+        pl.BlockSpec((bm, Np), lambda kb, i: (i, 0)),    # dy_out
+        pl.BlockSpec((bm,), lambda kb, i: (i,)),         # dg
+    ]
+    dw, db = pl.pallas_call(
+        _dw_kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda kb, i: (i, kb)),   # x
-            pl.BlockSpec((bk, Np), lambda kb, i: (kb, 0)),   # w
-            pl.BlockSpec((bm, Np), lambda kb, i: (i, 0)),    # y
-            pl.BlockSpec((bm, Np), lambda kb, i: (i, 0)),    # dy_out
-            pl.BlockSpec((bm,), lambda kb, i: (i,)),         # dg
-        ],
+        in_specs=[pl.BlockSpec((bm, bk), lambda kb, i: (i, kb))] + rows,
         out_specs=[
-            pl.BlockSpec((bm, bk), lambda kb, i: (i, kb)),   # dx
             pl.BlockSpec((bk, Np), lambda kb, i: (kb, 0)),   # dw
             pl.BlockSpec((Np,), lambda kb, i: (0,)),         # db
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Mp, Kp), x.dtype),
             jax.ShapeDtypeStruct((Kp, Np), w.dtype),
             jax.ShapeDtypeStruct((Np,), jnp.float32),
         ],
         interpret=interpret,
-    )(x, w, y, dy_out, dg)
+    )(x, y, dy_out, dg)
+    # only the dx kernel reads w: where dx is dead, so is this pad
+    w = jnp.pad(w, ((0, Kp - K), (0, Np - N)))
+    dx = pl.pallas_call(
+        _dx_kernel,
+        grid=grid,
+        in_specs=[pl.BlockSpec((bk, Np), lambda kb, i: (kb, 0))] + rows,
+        out_specs=pl.BlockSpec((bm, bk), lambda kb, i: (i, kb)),
+        out_shape=jax.ShapeDtypeStruct((Mp, Kp), x.dtype),
+        interpret=interpret,
+    )(w, y, dy_out, dg)
     return dx[:M, :K], dw[:K, :N], db[:N]
 
 

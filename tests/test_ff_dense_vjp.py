@@ -116,6 +116,31 @@ def test_norm_epilogue_grad_matches_oracle(M, K, N, key):
     np.testing.assert_allclose(gxf, gxr, rtol=1e-4, atol=1e-6)
 
 
+def _ragged_case(path, key):
+    """(loss, args) of the plain or the norm path on a shape whose M, K
+    and N are no tile multiples."""
+    M, K, N = 100, 333, 257
+    kx, kw, kv = jax.random.split(key, 3)
+    x = jax.random.normal(kx, (M, K), jnp.float32)
+    lp = {"w": jax.random.normal(kw, (K, N), jnp.float32) * K ** -0.5,
+          "b": jnp.full((N,), 0.1, jnp.float32)}
+    if path == "plain":
+        return _FUSED, (lp, x, 2.0, 0.3)
+    return _NORM_FUSED, (lp, x, jax.random.normal(kv, (N,), jnp.float32))
+
+
+@pytest.mark.parametrize("path", ["plain", "norm"])
+def test_param_grad_without_dx_is_bit_identical(path, key):
+    """A gradient of the parameters alone, as the trainers take it,
+    drops dx and with it the dx kernel; its dw and db must be the bits
+    of the gradient that keeps dx."""
+    loss, args = _ragged_case(path, key)
+    alone = jax.jit(jax.grad(loss, argnums=0))(*args)
+    with_dx, _ = jax.jit(jax.grad(loss, argnums=(0, 1)))(*args)
+    for name in ("w", "b"):
+        np.testing.assert_array_equal(alone[name], with_dx[name])
+
+
 # ---------------------------------------------------------------------------
 # Tuned block shapes through the custom_vjp: the autotuner hands
 # (bm, bn, bk) tuples down both fused paths — gradients must match the

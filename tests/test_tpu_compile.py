@@ -62,6 +62,11 @@ def _compiled_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _kernel_calls(text):
+    """Pallas kernel calls in a compiled module's text."""
+    return text.count('custom_call_target="tpu_custom_call"')
+
+
 @pytest.mark.parametrize("K,N", LAYERS)
 @pytest.mark.parametrize("norm", [False, True])
 def test_ff_dense_forward_compiles(one_chip, K, N, norm):
@@ -94,8 +99,23 @@ def test_ff_dense_grad_compiles(one_chip, K, N, fused):
 
     text = _compiled_text(jax.grad(loss), _spec(one_chip, (K, N)),
                           _spec(one_chip, (M, K)), _spec(one_chip, (N,)))
-    # the fused forward AND the fused backward kernel
-    assert text.count("tpu_custom_call") >= 2
+    # the fused forward and the dw/db kernel: the dx kernel is dead code
+    assert _kernel_calls(text) == 2
+
+
+@pytest.mark.parametrize("K,N", LAYERS)
+@pytest.mark.parametrize("fused", [ff_dense_vjp, ff_dense_norm_vjp],
+                         ids=["plain", "norm"])
+def test_ff_dense_grad_with_dx_compiles(one_chip, K, N, fused):
+    """Differentiating the input too keeps the dx kernel."""
+    def loss(w, x, b):
+        y, g = fused(x, w, b, False, None)
+        return jnp.sum(y) + jnp.sum(g)
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1)),
+                          _spec(one_chip, (K, N)), _spec(one_chip, (M, K)),
+                          _spec(one_chip, (N,)))
+    assert _kernel_calls(text) == 3
 
 
 def test_train_layer_chapter_compiles(one_chip, monkeypatch):
@@ -112,4 +132,26 @@ def test_train_layer_chapter_compiles(one_chip, monkeypatch):
         lp, opt, _spec(one_chip, (n, K)), _spec(one_chip, (n, K)),
         _spec(one_chip, (epochs,)), _spec(one_chip, (2,), jnp.uint32),
         batch=64, epochs=epochs, theta=2.0, impl="auto").compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    # the forward and the dw/db kernel; no dx kernel in the trainer
+    assert _kernel_calls(compiled.as_text()) == 2
+
+
+def test_train_layer_chapter_perf_opt_compiles(one_chip, monkeypatch):
+    """The norm path's trainer (paper §4.4, layer and local head) for
+    one paper-width chapter: it too differentiates the parameters only,
+    so its step holds the normed forward and the dw/db kernel."""
+    monkeypatch.setattr(ops, "_platform", lambda: "tpu")
+    K = N = 2000
+    n, epochs, classes = 6000, 1, 10
+    lp = {"w": _spec(one_chip, (K, N)), "b": _spec(one_chip, (N,))}
+    head = {"w": _spec(one_chip, (N, classes)),
+            "b": _spec(one_chip, (classes,))}
+    opt, opt_h = (jax.tree.map(lambda s: _spec(one_chip, s.shape, s.dtype),
+                               jax.eval_shape(optim.adam_init, p))
+                  for p in (lp, head))
+    compiled = ff_mlp.train_layer_chapter_perf_opt.lower(
+        lp, head, opt, opt_h, _spec(one_chip, (n, K)),
+        _spec(one_chip, (n,), jnp.int32), _spec(one_chip, (epochs,)),
+        _spec(one_chip, (2,), jnp.uint32), batch=64, epochs=epochs,
+        impl="auto").compile()
+    assert _kernel_calls(compiled.as_text()) == 2
