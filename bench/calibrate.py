@@ -6,8 +6,9 @@ cell's own size. The benchmark's runs never run this.
 
 For every seed: the program's check job, as a run's set-up drives it,
 against the reference (the sound readings: the lower end of a limit).
-For the first ``--controls`` seeds also, each put in the program's
-place and compared with the reference the same way:
+For the first ``--controls`` seeds also, each variant of the cell's
+family (``bench/families/<family>.py``) put in the program's place and
+compared with the reference the same way; the FF-MLP family's:
 
 - ``control``: the reference computed one precision step below the
   configuration's, three bfloat16 passes per product;
@@ -31,49 +32,24 @@ import json
 import sys
 import time
 
-from bench import check, reference, run
-
-LOOKS = ("program", "nudged")      # sound runs: their largest reading
+from bench import run
 
 
 def readings(cell, seed, devices, *, controls: bool, program=True,
              only=None, log=run.log):
-    """{variant: compared numbers} for one seed. Without ``program``
-    only the variants are read (against the reference), which needs one
-    chip whatever the cell's count."""
-    arrays, task, cfg, fit_kw, chapters = run.build(cell, seed, devices)
-    model = cell.model
-    out = {}
+    """{variant: compared numbers} for one seed, through the cell's
+    family. Without ``program`` only the variants are read (against the
+    reference), which needs one chip whatever the cell's count."""
+    fam = run.family(cell)
+    built = fam.build(cell, seed, devices)
     t0 = time.perf_counter()
-    if program:
-        *prog, acc = run.program_check(task, cfg, fit_kw, chapters)
+    prog = fam.check_job(built) if program else None
     t1 = time.perf_counter()
-    del task
-    x, y, x_test, y_test = arrays
-    ref = reference.run_job(run.check_model(model, chapters), seed, x, y,
-                            x_test, chapters)
-    ref_acc = float((ref.pred == y_test).mean())
-    if program:
-        out["program"] = check.readings(ref, *prog, acc, ref_acc)
-    log(f"seed {seed}: program check job {t1 - t0:.3f}s, reference "
-        f"{time.perf_counter() - t1:.3f}s")
-    if controls:
-        variants = {"control": {"precision": "bf16_3x"},
-                    "half_batch": {"fault": "half_batch"},
-                    "handoff_unnormed": {"fault": "handoff_unnormed"},
-                    "nudged": {"nudge": 1e-7},
-                    "handoff_bf16": {"handoff_precision": "bf16_3x"}}
-        if cell.traffic["num_nodes"] > 1:
-            variants["no_exchange"] = {
-                "exchange_nodes": cell.traffic["num_nodes"]}
-        for name, kw in variants.items():
-            if only and name not in only:
-                continue
-            v = reference.run_job(run.check_model(model, chapters), seed,
-                                  x, y, x_test, chapters, **kw)
-            out[name] = check.readings(ref, v.chapter0, v.final,
-                                       float((v.pred == y_test).mean()),
-                                       ref_acc)
+    names = [v for v in fam.variants(cell) if controls
+             and (not only or v in only)]
+    out = fam.calibration_readings(cell, seed, built, prog, names)
+    log(f"seed {seed}: program check job {t1 - t0:.3f}s, reference and "
+        f"{len(names)} variants {time.perf_counter() - t1:.3f}s")
     return out
 
 
@@ -88,10 +64,13 @@ def main(argv=None):
                     help="comma-separated variants to read (default: all)")
     args = ap.parse_args(argv)
     cell = run.find_cell(args.workload)
+    fam = run.family(cell)
+    looks = ("program",) + fam.SOUND    # sound runs: their largest reading
     run.environment()
     devices = run.tpu_devices(1 if args.no_program
                               else cell.traffic["chips"])
-    devices = devices * (cell.traffic["num_nodes"] // len(devices))
+    devices = devices * (cell.traffic.get("num_nodes", 1) // len(devices)
+                         or 1)
     seeds = [int(s) for s in args.seeds.split(",")]
     worst = {}
     for i, seed in enumerate(seeds):
@@ -102,16 +81,16 @@ def main(argv=None):
                                            if v]).items():
             row = {"workload": args.workload, "seed": seed,
                    "variant": variant,
-                   **{k: got[k] for k in check.NAMES + ("accuracy",)
+                   **{k: got[k] for k in fam.NAMES + ("accuracy",)
                       if k in got}}
             print(json.dumps(row), flush=True)
             w = worst.setdefault(variant, {})
-            for k in check.NAMES:
+            for k in fam.NAMES:
                 if k in got:
-                    w[k] = (max if variant in LOOKS else min)(
+                    w[k] = (max if variant in looks else min)(
                         w.get(k, got[k]), got[k])
     print(json.dumps({"workload": args.workload,
-                      "max_of_program_and_nudged_min_of_others": worst}),
+                      "max_of_sound_min_of_others": worst}),
           flush=True)
     sys.stdout.flush()
 

@@ -29,7 +29,7 @@ negatives regenerated by scoring through every layer):
 
 A state returned unchanged reads exactly 1 on each per-unit number. A
 cell compares the numbers its limits file (``bench/limits/<cell>.json``)
-names; the calibration prints them all.
+names (``decide``, for every family); the calibration prints them all.
 """
 from __future__ import annotations
 
@@ -78,8 +78,11 @@ def readings(ref, prog0, prog, prog_acc, ref_acc):
 
 
 def decide(values, limits):
-    """(correct, {name: {"value", "limit"}}) for the numbers the cell's
-    limits name."""
-    checks = {n: {"value": values[n], "limit": limits[n]} for n in NAMES
-              if n in limits}
-    return all(c["value"] <= c["limit"] for c in checks.values()), checks
+    """(correct, {name: {"value", "limit"}}) for every number the cell's
+    limits file names (all its keys but ``set_from``). A number the
+    family did not read has the value None and makes the run not
+    correct."""
+    checks = {n: {"value": values.get(n), "limit": lim}
+              for n, lim in limits.items() if n != "set_from"}
+    return all(c["value"] is not None and c["value"] <= c["limit"]
+               for c in checks.values()), checks

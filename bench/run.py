@@ -3,22 +3,42 @@
     python3 -m bench.run --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 Everything a cell needs is found by name from ``BENCHMARK.json``: the
-workload names its configuration (``bench/configs/<config>.json``, the
-``FFMLPConfig`` fields as run) and its traffic mix
-(``bench/traffic/<traffic>.json``: backend, schedule, nodes, chips and
-data sizes); the comparison's limits are ``bench/limits/<workload>.json``
-and each per-layer metric is read by ``bench/metrics/<metric>.py``.
+workload names its configuration (``bench/configs/<config>.json``) and
+its traffic mix (``bench/traffic/<traffic>.json``); the comparison's
+limits are ``bench/limits/<workload>.json`` and each per-layer metric
+is read by ``bench/metrics/<metric>.py``. The configuration's
+``"family"`` (``ff_mlp`` where it names none) selects
+``bench/families/<family>.py``, which holds all that depends on what
+is trained:
 
-Set-up (``setup_s``): imports, the data made on the device from the
-seed, the persistent compile cache, and one check job: the cell's job
-through ``repro.api.fit`` cut to max(nodes, 2) chapters, which compiles
-every program of the window on every device and whose trained leaves
-are compared with the reference after the window. With ``--trace 0``
-the window then runs whole jobs back to back while less than
-``--seconds`` have passed, and reports ``train_samples_per_s``: train
-samples times mini-epochs per job times jobs, over the first job's
-start to the last job's end. With ``--trace 1`` one whole job runs
-under the profiler and the per-layer metrics are read from its trace.
+- ``build(cell, seed, devices)``: the inputs and weights from the seed,
+  as an object whose ``args`` and ``kwargs`` are the timed
+  ``repro.api.fit`` call;
+- ``check_job(built)``: set-up's check job through the same entry and
+  compiled programs, whose result the comparison reads;
+- ``calibration_readings(cell, seed, built, prog, names=())``: the
+  plain reference and the compared numbers of the program's check job
+  (``prog``) under ``"program"``, every one of them a name in
+  ``NAMES``, and of each calibration variant in ``names``;
+- ``samples_per_job``, ``job_kernel_calls``, ``job_model_flops`` and
+  ``layer_steps_per_job`` of (model, traffic): the counts the window's
+  rate and the per-layer readers use (a sample is the family's own
+  unit);
+- ``variants`` and ``SOUND`` for ``bench.calibrate``.
+
+A configuration of another family is one more file under
+``bench/families/`` beside its configuration, traffic and limits files.
+
+Set-up (``setup_s``): imports, the family's inputs made from the seed,
+the persistent compile cache, and the check job, which compiles every
+program of the window on every device. With ``--trace 0`` the window
+then runs whole jobs (``api.fit(*built.args, **built.kwargs)``) back to
+back while less than ``--seconds`` have passed, and reports
+``train_samples_per_s``: samples per job times jobs, over the first
+job's start to the last job's end. With ``--trace 1`` one whole job
+runs under the profiler and the per-layer metrics are read from its
+trace. After the window, and once the program's state is freed, the
+family's readings are compared with the limits (``bench.check.decide``).
 
 The last line of standard output is one JSON object; the compared
 numbers and their limits are also the last lines of standard error.
@@ -62,13 +82,18 @@ class Cell:
     limits: dict          # bench/limits/<workload>.json
     end_to_end: list      # BENCHMARK.json metrics this cell reports
     per_layer: list
+    root: str = ROOT      # the checkout the files were found in
+
+    @property
+    def family(self) -> str:
+        return self.config.get("family", "ff_mlp")
 
     @property
     def model(self) -> dict:
-        """The ``FFMLPConfig`` fields of the configuration file."""
+        """The configuration file's fields of the model as run."""
         return {k: v for k, v in self.config.items()
-                if k not in ("source", "reduced", "assumed", "precision",
-                             "dataset")}
+                if k not in ("family", "source", "reduced", "assumed",
+                             "precision", "dataset")}
 
 
 def _json(path):
@@ -94,17 +119,30 @@ def find_cell(name, root=ROOT) -> Cell:
         traffic=_json(os.path.join(d, "traffic", wl["traffic"] + ".json")),
         limits=_json(os.path.join(d, "limits", name + ".json")),
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, name)],
-        per_layer=[m for m in bench["per_layer"] if _applies(m, name)])
+        per_layer=[m for m in bench["per_layer"] if _applies(m, name)],
+        root=root)
+
+
+def _module(path, name):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod       # where dataclasses look up its names
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def family(cell: Cell):
+    """The module ``bench/families/<family>.py`` of the cell's
+    configuration."""
+    return _module(os.path.join(cell.root, "bench", "families",
+                                cell.family + ".py"),
+                   "bench_family_" + cell.family)
 
 
 def metric_reader(name, root=ROOT):
     """``read(ctx)`` of ``bench/metrics/<name>.py``."""
-    path = os.path.join(root, "bench", "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(os.path.join(root, "bench", "metrics", name + ".py"),
+                   "bench_metric_" + name.replace(".", "_")).read
 
 
 # ---------------------------------------------------------------------------
@@ -118,7 +156,7 @@ class Context:
     traffic: dict
     chips: int
     peak: object          # peaks.Peak
-    job_calls: list       # flops.KernelCalls of one job
+    job_calls: list       # the family's kernel calls of one job
     job_flops: float      # model FLOPs of one job
     layer_steps: int      # layer steps of one job
 
@@ -166,110 +204,39 @@ def tpu_devices(chips):
     return devs[:chips]
 
 
-def build(cell: Cell, seed: int, devices):
-    """(arrays, task, cfg, fit keywords, check chapters) of one run."""
-    import jax
-    from repro import data as data_lib
-    from repro.configs.ff_mlp import FFMLPConfig
+def _gc_timer(pauses):
+    """A ``gc.callbacks`` entry that appends each collection's seconds
+    to ``pauses``."""
+    start = []
 
-    from bench import data
-
-    model, traffic = cell.model, cell.traffic
-    nodes = traffic["num_nodes"]
-    arrays = data.mnist_like(data.seed_key(seed), n_train=traffic["n_train"],
-                             n_test=traffic["n_test"])
-    jax.block_until_ready(arrays)
-    task = data_lib.ImageTask(*arrays, model["num_classes"],
-                              arrays[0].shape[1])
-    cfg = FFMLPConfig(**{**model, "layer_sizes": tuple(model["layer_sizes"])},
-                      seed=seed)
-    fit_kw = {"backend": traffic["backend"]}
-    if traffic["backend"] == "executor":
-        fit_kw.update(schedule=traffic["schedule"], num_nodes=nodes,
-                      devices=devices[:nodes])
-    return arrays, task, cfg, fit_kw, max(nodes, 2)
-
-
-def program_check(task, cfg, fit_kw, chapters):
-    """The check job: the window's job through ``api.fit``, cut to its
-    first ``chapters`` chapters. The goodness strategy's chapter
-    trainer is wrapped for this job only, to keep a copy of each
-    layer's state after chapter 0. Returns (leaves after chapter 0,
-    trained leaves, test accuracy)."""
-    import jax
-    import jax.numpy as jnp
-    from repro import api
-    from repro.core import strategies
-
-    from bench import check
-
-    good = strategies.goodness.get(cfg.goodness_fn)
-    n_layers = len(cfg.layer_sizes) - 1
-    first = []
-
-    def keep_chapter0(state, *args, **kw):
-        out = good.train_chapter(state, *args, **kw)
-        if len(first) < n_layers:
-            first.append(jax.tree.map(jnp.copy, good.export([out])))
-        return out
-
-    strategies.register_goodness(
-        cfg.goodness_fn, dataclasses.replace(good,
-                                             train_chapter=keep_chapter0),
-        overwrite=True)
-    try:
-        cut = check_model({"epochs": cfg.epochs, "splits": cfg.splits},
-                          chapters)
-        res = api.fit(dataclasses.replace(cfg, **cut), task, **fit_kw)
-    finally:
-        strategies.register_goodness(cfg.goodness_fn, good, overwrite=True)
-    ch0 = {g: [f[g][0] for f in first] for g in first[0]}
-    return check.leaves_of(ch0), check.leaves_of(res.params), res.test_acc
-
-
-def check_model(model, chapters):
-    """The configuration of the check job: the same chapters and
-    mini-epochs per chapter, cut to ``chapters`` chapters."""
-    per_chapter = max(model["epochs"] // model["splits"], 1)
-    return {**model, "splits": chapters, "epochs": chapters * per_chapter}
-
-
-def reference_readings(model, seed, arrays, chapters, prog, prog_acc, **kw):
-    """The reference over the check job's chapters, and the compared
-    numbers for the program's (leaves after chapter 0, trained
-    leaves)."""
-    import jax.numpy as jnp
-
-    from bench import check, reference
-
-    model = check_model(model, chapters)
-    x, y, x_test, y_test = arrays
-    ref = reference.run_job(model, seed, x, y, x_test, chapters, **kw)
-    ref_acc = float(jnp.mean(ref.pred == y_test))
-    got = check.readings(ref, prog[0], prog[1], prog_acc, ref_acc)
-    return got, (ref, ref_acc)
+    def timer(phase, info):
+        if phase == "start":
+            start[:] = [time.perf_counter()]
+        elif start:
+            pauses.append(time.perf_counter() - start[0])
+    return timer
 
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
              meter, *, t_start=T_START, peak=None):
     """Set-up, window or traced job, then the comparison. Returns the
     result object."""
-    import jax
     from repro import api
 
-    from bench import check, flops, tracefile
+    from bench import check, tracefile
 
+    fam = family(cell)
     model, traffic = cell.model, cell.traffic
     t0 = time.perf_counter()
-    arrays, task, cfg, fit_kw, chapters = build(cell, seed, devices)
-    log(f"setup: data {time.perf_counter() - t0:.3f}s")
+    built = fam.build(cell, seed, devices)
+    log(f"setup: {cell.family} inputs {time.perf_counter() - t0:.3f}s")
     c0 = meter.snapshot()
     t0 = time.perf_counter()
-    *prog_leaves, prog_acc = program_check(task, cfg, fit_kw, chapters)
+    prog = fam.check_job(built)
     c1 = meter.snapshot()
-    log(f"setup: check job ({chapters} chapters) {time.perf_counter() - t0:.3f}"
-        f"s, {c1[1] - c0[1]} compiles in {c1[0] - c0[0]:.3f}s, "
-        f"{c1[2] - c0[2]} persistent-cache hits")
+    log(f"setup: check job {time.perf_counter() - t0:.3f}s, {c1[1] - c0[1]} "
+        f"compiles in {c1[0] - c0[0]:.3f}s, {c1[2] - c0[2]} persistent-cache"
+        f" hits")
     # a run that compiled has just written its programs to the cache:
     # flush them now, not while the window runs
     os.sync()
@@ -277,25 +244,32 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     log(f"setup: {setup_s:.3f}s in all; {c1[1]} compiles in {c1[0]:.3f}s, "
         f"{c1[2]} persistent-cache hits since start")
 
-    samples = flops.train_samples_per_job(model, traffic)
     result = {"correct": None, "attempted": 0, "failed": 0, "metrics": {},
               "device": {}}
     if not trace:
         c0 = meter.snapshot()
-        jobs, t0 = 0, time.perf_counter()
-        while True:
-            res = api.fit(cfg, task, **fit_kw)
-            jobs += 1
-            t1 = time.perf_counter()
-            if t1 - t0 >= seconds:
-                break
+        pauses = []                 # the collector's passes in the window
+        gc.callbacks.append(_gc_timer(pauses))
+        ends = [time.perf_counter()]
+        try:
+            while True:
+                res = api.fit(*built.args, **built.kwargs)
+                ends.append(time.perf_counter())
+                if ends[-1] - ends[0] >= seconds:
+                    break
+        finally:
+            gc.callbacks.pop()
         del res
         c1 = meter.snapshot()
+        jobs, t0, t1 = len(ends) - 1, ends[0], ends[-1]
         log(f"window: {jobs} jobs in {t1 - t0:.3f}s, {c1[1] - c0[1]} "
-            f"compiles in the window")
+            f"compiles in the window, {len(pauses)} garbage collections in "
+            f"{sum(pauses):.3f}s (longest {max(pauses, default=0):.3f}s)")
+        log("window: job seconds " + " ".join(
+            f"{b - a:.4f}" for a, b in zip(ends, ends[1:])))
         result["attempted"] = jobs
-        values = {"train_samples_per_s": samples_per_s(samples, jobs,
-                                                        t1 - t0),
+        values = {"train_samples_per_s": samples_per_s(
+                      fam.samples_per_job(model, traffic), jobs, t1 - t0),
                   "setup_s": setup_s}
     else:
         import jax.profiler
@@ -306,7 +280,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
             jax.profiler.start_trace(log_dir, profiler_options=opts)
             with jax.profiler.TraceAnnotation("bench:job"):
                 t0 = time.perf_counter()
-                res = api.fit(cfg, task, **fit_kw)
+                res = api.fit(*built.args, **built.kwargs)
                 t1 = time.perf_counter()
             jax.profiler.stop_trace()
             del res
@@ -317,10 +291,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
                 tracefile.Event("", lo, hi - lo)]
             log(f"trace: {len(evs)} program and "
                 f"{sum(map(len, tr.ops.values()))} op events on "
-                f"{len(tr.programs)} devices, from "
-                f"{min(e.start_ns for e in evs) - lo:.0f} ns after the job's"
-                f" start to {max(e.end_ns for e in evs) - hi:.0f} ns after "
-                f"its end")
+                f"{len(tr.programs)} devices, {len(tr.host)} host events, "
+                f"from {min(e.start_ns for e in evs) - lo:.0f} ns after the "
+                f"job's start to {max(e.end_ns for e in evs) - hi:.0f} ns "
+                f"after its end")
             reduced = tracefile.reduce(tr, (lo, hi))
             log(f"trace: job {t1 - t0:.3f}s, trace written and read in "
                 f"{time.perf_counter() - t2:.3f}s, window "
@@ -330,12 +304,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
         result["attempted"] = 1
         ctx = Context(reduced=reduced, model=model, traffic=traffic,
                       chips=len(devices), peak=peak,
-                      job_calls=flops.job_kernel_calls(model, traffic),
-                      job_flops=flops.job_model_flops(model, traffic),
-                      layer_steps=flops.layer_steps_per_job(model, traffic))
+                      job_calls=fam.job_kernel_calls(model, traffic),
+                      job_flops=fam.job_model_flops(model, traffic),
+                      layer_steps=fam.layer_steps_per_job(model, traffic))
         values = {}
         for m in cell.per_layer:
-            v = metric_reader(m["name"])(ctx)
+            v = metric_reader(m["name"], cell.root)(ctx)
             if v is None:
                 log(f"trace: {m['name']}: nothing to read")
             else:
@@ -356,18 +330,22 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
         **result["device"]}
 
     # the comparison, once the program's state is freed
-    del task
     gc.collect()
     t0 = time.perf_counter()
-    got, _ = reference_readings(model, seed, arrays, chapters, prog_leaves,
-                                prog_acc)
+    got = fam.calibration_readings(cell, seed, built, prog)["program"]
     correct, checks = check.decide(got, cell.limits)
-    log(f"check: reference over {chapters} chapters in "
-        f"{time.perf_counter() - t0:.3f}s; test accuracy {got['accuracy']}")
+    log(f"check: reference in {time.perf_counter() - t0:.3f}s")
+    for name, v in got.items():
+        if name not in checks:
+            log(f"check: {name} {v!r} (not compared)")
     result["correct"] = correct
     if not correct:
         result["failed"] = result["attempted"]
     result["checks"] = checks
+    for name, c in checks.items():
+        if c["value"] is None:
+            log(f"check: {name}: the {cell.family} family read no such "
+                f"number")
     for name, c in checks.items():
         log(f"{name} {c['value']!r} limit {c['limit']!r}")
     return result

@@ -16,10 +16,9 @@ Together with the idle time outside every program span (key ``""``)
 they add up to ``idle_share``. A trace with none of a layer's spans
 (a program without them) reads ``None`` for that layer.
 
-``program_spans`` reads the spans from every line of the host plane:
-the main thread's line is named after the process, ``python3`` when
-the benchmark's command starts it, and ``tracefile.load`` reads only
-lines named ``python`` or ``main/<tid>``.
+``program_spans`` reads the spans from every line of the host plane
+(``tracefile.load`` reads the main thread's, named after the process:
+``python3`` when the benchmark's command starts it).
 
     python3 -m bench.spans --workload <name> --seed <n>
 
@@ -154,12 +153,12 @@ def main(argv=None):
     import jax.profiler
     from repro import api
 
-    _, task, cfg, fit_kw, _ = run.build(cell, args.seed, devices)
-    api.fit(cfg, task, **fit_kw)          # compiles every program
+    built = run.family(cell).build(cell, args.seed, devices)
+    api.fit(*built.args, **built.kwargs)  # compiles every program
     untraced = []
     for _ in range(args.jobs):
         t0 = time.perf_counter()
-        api.fit(cfg, task, **fit_kw)
+        api.fit(*built.args, **built.kwargs)
         untraced.append(time.perf_counter() - t0)
     log_dir = tempfile.mkdtemp(prefix="bench_spans_")
     try:
@@ -168,7 +167,7 @@ def main(argv=None):
         jax.profiler.start_trace(log_dir, profiler_options=opts)
         with jax.profiler.TraceAnnotation("bench:job"):
             t0 = time.perf_counter()
-            api.fit(cfg, task, **fit_kw)
+            api.fit(*built.args, **built.kwargs)
             traced = time.perf_counter() - t0
         jax.profiler.stop_trace()
         tr = tracefile.load(log_dir)

@@ -3,6 +3,8 @@ and bytes per ``ff_dense`` call, model FLOPs per job, and the trace
 reduction."""
 import json
 import os
+import subprocess
+import sys
 
 import pytest
 
@@ -10,6 +12,7 @@ from bench import flops, peaks, tracefile
 from bench.tracefile import Event
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
 
 
 def _load(kind, name):
@@ -136,3 +139,33 @@ def test_trace_reduction_on_hand_built_events():
 ])
 def test_op_parts(name, expected):
     assert tracefile.op_parts(name) == expected
+
+
+_PROFILED = """
+import json, sys
+import jax, jax.numpy as jnp
+from bench import tracefile
+jax.profiler.start_trace(sys.argv[1])
+with jax.profiler.TraceAnnotation("bench:job"):
+    with jax.profiler.TraceAnnotation("task:wait"):
+        jnp.ones(8).block_until_ready()
+jax.profiler.stop_trace()
+tr = tracefile.load(sys.argv[1])
+print(json.dumps({"host": [e.name for e in tr.host], "marks": list(tr.marks)}))
+"""
+
+
+def test_load_reads_the_main_thread_named_after_the_process(tmp_path):
+    # the profiler names the main thread's line after the process: a
+    # command started as python3, as the benchmark's is, gives "python3"
+    exe = tmp_path / "python3"
+    exe.symlink_to(sys.executable)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(p for p in sys.path if p))
+    r = subprocess.run([str(exe), "-c", _PROFILED, str(tmp_path / "trace")],
+                       cwd=ROOT, env=env, capture_output=True, text=True,
+                       timeout=300)
+    assert r.returncode == 0, r.stderr
+    got = json.loads(r.stdout.strip().splitlines()[-1])
+    assert got["marks"] == ["bench:job"]
+    assert "task:wait" in got["host"]

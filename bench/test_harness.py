@@ -1,6 +1,7 @@
-"""The harness on the CPU: cells found by name, the window's rate, the
-refusal without a TPU, and whole runs at a tiny size, sound and with
-the timed path broken underneath (``correct`` must come out false)."""
+"""The harness on the CPU: cells found by name, a configuration of
+another family taken by new files alone, the window's rate, the refusal
+without a TPU, and whole runs at a tiny size, sound and with the timed
+path broken underneath (``correct`` must come out false)."""
 import dataclasses
 import json
 import os
@@ -31,13 +32,15 @@ def _untuned(tmp_path, monkeypatch):
 @pytest.mark.parametrize("name", CELLS)
 def test_cell_files_are_found_by_name(name):
     cell = run.find_cell(name)
+    fam = run.family(cell)
     assert cell.traffic["chips"] == cell.workload["chips"]
-    assert set(cell.limits) - {"set_from"} <= set(check.NAMES)
+    assert set(cell.limits) - {"set_from"} <= set(fam.NAMES)
     assert {m["name"] for m in cell.end_to_end} >= {"setup_s",
                                                       "train_samples_per_s"}
     for m in cell.per_layer:
         assert callable(run.metric_reader(m["name"]))
-    assert set(cell.model["layer_sizes"]) == {784, 2000}
+    if cell.family == "ff_mlp":
+        assert set(cell.model["layer_sizes"]) == {784, 2000}
 
 
 def test_per_layer_workloads_key_limits_a_metric_to_its_cells():
@@ -45,6 +48,135 @@ def test_per_layer_workloads_key_limits_a_metric_to_its_cells():
     assert "neg_gen_share" in names("mnist_adaptive.seq_1chip")
     assert "neg_gen_share" not in names("mnist_random.seq_1chip")
     assert "mfu" in names("mnist_random.seq_1chip")
+
+
+# ---------------------------------------------------------------------------
+# A configuration of another family, by new files alone
+# ---------------------------------------------------------------------------
+
+_STUB_FAMILY = '''"""A family of the harness's tests: the smallest job ``repro.api.fit``
+takes, one chapter of one mini-epoch on the program's host-side MNIST
+stand-in, with one reading of its own, ``error_rate``: one minus the
+check job's test accuracy."""
+import dataclasses
+
+NAMES = ("error_rate",)
+SOUND = ()
+BATCH = 64
+
+
+@dataclasses.dataclass
+class Built:
+    args: tuple
+    kwargs: dict
+
+
+def build(cell, seed, devices):
+    from repro import data as data_lib
+    from repro.configs.ff_mlp import FFMLPConfig
+
+    rows = cell.traffic["rows"]
+    task = data_lib.mnist_like(n_train=rows, n_test=rows, seed=seed)
+    cfg = FFMLPConfig(layer_sizes=tuple(cell.model["layer_sizes"]),
+                      epochs=1, splits=1, batch_size=BATCH,
+                      neg_mode="random", seed=seed)
+    return Built((cfg, task), {"backend": "sequential"})
+
+
+def check_job(built):
+    from repro import api
+    return api.fit(*built.args, **built.kwargs).test_acc
+
+
+def variants(cell):
+    return {}
+
+
+def calibration_readings(cell, seed, built, prog, names=()):
+    return {} if prog is None else {"program": {"error_rate": 1.0 - prog}}
+
+
+def samples_per_job(model, traffic):
+    return traffic["rows"]
+
+
+def job_kernel_calls(model, traffic):
+    return []
+
+
+def layer_steps_per_job(model, traffic):
+    return -(-traffic["rows"] // BATCH) * (len(model["layer_sizes"]) - 1)
+
+
+def job_model_flops(model, traffic):
+    sizes = model["layer_sizes"]
+    return sum(2 * 2.0 * (2 * traffic["rows"]) * k * n
+               for k, n in zip(sizes, sizes[1:]))
+'''
+
+
+def _stub_root(root, limits):
+    """A checkout holding the benchmark's ``BENCHMARK.json`` with one
+    more configuration and cell, whose configuration names the family
+    ``stub``, and only the new files that cell needs: its
+    configuration, traffic and limits, and ``bench/families/stub.py``."""
+    bench = run._json(os.path.join(ROOT, "BENCHMARK.json"))
+    bench["configs"].append({
+        "name": "stub_mlp", "source": "https://arxiv.org/abs/2404.08573",
+        "file": "bench/configs/stub_mlp.json", "reduced": [],
+        "why": "a family of the harness's tests"})
+    bench["workloads"].append({
+        "name": "stub_mlp.one", "config": "stub_mlp", "traffic": "rows",
+        "chips": 1, "why": "one tiny job a window"})
+    files = {"BENCHMARK.json": bench,
+             "bench/configs/stub_mlp.json": {"family": "stub",
+                                             "source": "a tiny FF layer",
+                                             "layer_sizes": [784, 16]},
+             "bench/traffic/rows.json": {"chips": 1, "rows": 128},
+             "bench/limits/stub_mlp.one.json": limits}
+    for rel, obj in files.items():
+        os.makedirs(os.path.dirname(os.path.join(root, rel)), exist_ok=True)
+        with open(os.path.join(root, rel), "w") as f:
+            json.dump(obj, f)
+    os.makedirs(os.path.join(root, "bench", "families"))
+    with open(os.path.join(root, "bench", "families", "stub.py"), "w") as f:
+        f.write(_STUB_FAMILY)
+    return run.find_cell("stub_mlp.one", root=str(root))
+
+
+def _go_stub(cell):
+    return run.run_cell(cell, 2 ** 31 + 11, 0.1, False, jax.devices()[:1],
+                        compiles.CompileMeter(), t_start=0.0,
+                        peak=peaks.peak_for("TPU v5 lite"))
+
+
+def test_another_family_runs_by_new_files_alone(tmp_path):
+    cell = _stub_root(tmp_path, {"error_rate": 1.0})
+    assert cell.family == "stub" and "family" not in cell.model
+    fam = run.family(cell)
+    assert fam.NAMES == ("error_rate",)
+    assert fam.samples_per_job(cell.model, cell.traffic) == 128
+    # the FF-MLP cells' own per-layer metrics do not hold it
+    assert {m["name"] for m in cell.per_layer} == {"mfu", "idle_share"}
+    res = _go_stub(cell)
+    assert res["correct"] is True, res["checks"]
+    assert set(res["checks"]) == {"error_rate"}
+    assert set(res["metrics"]) == {"train_samples_per_s", "setup_s"}
+    assert res["attempted"] >= 1 and res["failed"] == 0
+
+
+def test_a_limit_on_a_number_the_family_does_not_read_fails(tmp_path,
+                                                           capsys):
+    assert check.decide({"a": 0.5}, {"a": 1.0, "set_from": "x"}) == (
+        True, {"a": {"value": 0.5, "limit": 1.0}})
+    assert check.decide({"a": 0.5}, {"a": 1.0, "b": 1.0})[0] is False
+    res = _go_stub(_stub_root(tmp_path, {"error_rate": 1.0,
+                                         "final_unit_diff_l0": 1.0}))
+    assert res["correct"] is False
+    assert res["checks"]["final_unit_diff_l0"]["value"] is None
+    assert res["failed"] == res["attempted"] > 0
+    assert "final_unit_diff_l0: the stub family read no such number" \
+        in capsys.readouterr().err
 
 
 def test_unknown_workload_is_refused():

@@ -3,10 +3,10 @@
 ``load`` reads the ``.xplane.pb`` that ``jax.profiler`` writes with
 ``jax.profiler.ProfileData``: per device, the program executions of
 the "XLA Modules" line and the operations of the "XLA Ops" line, and
-the host's main thread ("python" and "main/<tid>" lines), whose
+the host's main thread (its line is named after the process, such as
+"python3", or "main/<tid>"), whose program spans and
 ``PjitFunction(...)``, dispatch and transfer spans say what the host
-was doing. ``reduce`` is a pure function of
-those events. On a TPU an operation's event name is its HLO text, so a
+was doing. ``reduce`` is a pure function of those events. On a TPU an operation's event name is its HLO text, so a
 kernel's call shows as ``%<name> = <shapes> custom-call(...)``.
 """
 from __future__ import annotations
@@ -187,8 +187,7 @@ def load(log_dir: str) -> Trace:
                     if e.name.startswith("bench:"):
                         marks[e.name] = (e.start_ns, e.start_ns
                                          + e.duration_ns)
-                    elif line.name == "python" or line.name.startswith(
-                            "main/"):
+                    elif line.name.startswith(("python", "main/")):
                         host.append(Event(e.name, e.start_ns,
                                           e.duration_ns))
     return Trace(programs, ops, host, marks)
