@@ -51,7 +51,7 @@ def run(arch="qwen2-0.5b", blocks=4, chapters=4, steps_per_chapter=8,
             cfg.vocab, batch, seq, steps_per_chapter,
             seed=chapter * 1009 + block))
 
-    params_c, records, _ = pff_lm.train_chapters(
+    params_c, records, _, _ = pff_lm.train_chapters(
         cfg, data_iter, chapters=chapters,
         steps_per_chapter=steps_per_chapter, lr=lr)
     ce_chap = float(train_lib.eval_ce(params_c, cfg, eval_tokens))

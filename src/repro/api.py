@@ -114,6 +114,7 @@ class FitResult:
     profile: Optional[dict] = None
     resilience: Optional[dict] = None
     eval_ce: Optional[float] = None         # LM chapter backends: val CE
+    head_history: list = dataclasses.field(default_factory=list)
     serve: Optional["ServeResult"] = None   # fit(serve=ServeConfig(...))
     trace: Optional[object] = None          # obs.trace.Tracer (trace=...)
     raw: object = None
@@ -476,6 +477,7 @@ def _fit_lm_chapters(cfg, source, *, backend, schedule, num_nodes,
     reported CE."""
     import time
 
+    import jax
     import jax.numpy as jnp
 
     from repro.core import pff_lm
@@ -490,16 +492,18 @@ def _fit_lm_chapters(cfg, source, *, backend, schedule, num_nodes,
                                            steps=steps_per_chapter)
         with tracer.span("fit:lm_sequential", chapters=chapters):
             t0 = time.perf_counter()
-            params, records, losses = pff_lm.train_chapters(
+            params, records, losses, head_losses = pff_lm.train_chapters(
                 cfg, data_iter, chapters=chapters,
                 steps_per_chapter=steps_per_chapter, lr=lr,
-                head_lr=head_lr, seed=seed)
+                head_lr=head_lr, seed=seed, tracer=tracer)
             makespan = time.perf_counter() - t0
+        steps, head_steps = ([float(v) for a in jax.device_get(ls)
+                              for v in a] for ls in (losses, head_losses))
         fres = FitResult(backend=backend, cfg=cfg, params=params,
                          schedule="sequential", num_nodes=1,
                          records=records, makespan=makespan,
-                         history=[(i + 1, l)
-                                  for i, l in enumerate(losses)])
+                         history=list(enumerate(steps, 1)),
+                         head_history=list(enumerate(head_steps, 1)))
     else:
         schedule = schedule or ("sequential" if num_nodes == 1
                                 else "all_layers")
@@ -517,8 +521,9 @@ def _fit_lm_chapters(cfg, source, *, backend, schedule, num_nodes,
                                   else None),
                          trace=res.trace, raw=res)
     # one eval path for BOTH backends: held-out CE on a fixed val draw
-    ev = jnp.asarray(source.blocks("val", 16, seed=321))
-    fres.eval_ce = float(train_lib.eval_ce(fres.params, cfg, ev))
+    with tracer.span("fit:eval", rows=16):
+        ev = jnp.asarray(source.blocks("val", 16, seed=321))
+        fres.eval_ce = float(train_lib.eval_ce(fres.params, cfg, ev))
     return fres
 
 

@@ -20,19 +20,67 @@ from typing import Optional, Tuple
 #   cross_attn  — cross-attention to auxiliary embeddings (VLM / decoder)
 #   mamba2      — SSD state-space block
 #   rglru       — RG-LRU recurrent block
-# Every block is followed by its MLP (dense or MoE) unless mlp="none".
+#   attn_dense  — attn followed by the dense MLP (d_ff) even where cfg.moe
+#                 is set: the leading dense layers of a DeepSeekMoE stack
+#   mla_dense   — multi-head latent attention (cfg.mla) + dense MLP
+#   mla_moe     — multi-head latent attention + the MoE (cfg.moe)
+# Every block is followed by its MLP unless mlp="none": kinds that name
+# none take the MoE where cfg.moe is set, else the dense MLP.
 # ---------------------------------------------------------------------------
 
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """``num_experts`` is what the router scores over; this chip holds
+    ``held`` of them (0: all), experts ``[shard * held, (shard + 1) *
+    held)``, and computes their part of each token's routed sum only.
+    ``capacity_factor`` None routes every assignment (no token dropped);
+    a number drops assignments past that multiple of the mean load.
+    ``norm_topk`` renormalises the top-k gates to sum to one.
+    ``router_aux_weight`` weighs the load-balance loss in every loss the
+    layer enters (the FF block loss included)."""
     num_experts: int
     top_k: int
     expert_ff: int
     num_shared: int = 0
     shared_ff: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: Optional[float] = 1.25
     router_aux_weight: float = 0.01
+    held: int = 0
+    shard: int = 0
+    norm_topk: bool = True
+
+    @property
+    def held_experts(self) -> int:
+        return self.held or self.num_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2), without a query
+    low-rank: q = x W_q (nope + rope dims a head); [c_kv, k_pe] =
+    x W_kv_a; [k_nope, v] = RMSNorm(c_kv) W_kv_b; k_pe is one rope head
+    shared by every head."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN rope scaling as DeepSeek-V2 applies it (its
+    ``rope_scaling`` of type "yarn")."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,6 +133,8 @@ class ModelConfig:
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
     rglru: Optional[RGLRUConfig] = None
+    mla: Optional[MLAConfig] = None
+    rope_scaling: Optional[YaRNConfig] = None
     ff: FFConfig = dataclasses.field(default_factory=FFConfig)
     # encoder-decoder (audio) ---------------------------------------------
     enc_dec: bool = False
@@ -96,6 +146,7 @@ class ModelConfig:
     # training -------------------------------------------------------------
     dtype: str = "bfloat16"
     remat: bool = True
+    seed: int = 0                        # weights and negatives (api.fit)
     source: str = ""                     # citation
 
     @property
@@ -136,7 +187,12 @@ class ModelConfig:
             moe = dataclasses.replace(
                 self.moe, num_experts=4, top_k=2, expert_ff=2 * d_model,
                 num_shared=min(self.moe.num_shared, 1),
-                shared_ff=2 * d_model if self.moe.num_shared else 0)
+                shared_ff=2 * d_model if self.moe.num_shared else 0,
+                held=2 if self.moe.held else 0, shard=0)
+        mla = None
+        if self.mla is not None:
+            mla = MLAConfig(kv_lora_rank=64, qk_nope_head_dim=32,
+                            qk_rope_head_dim=16, v_head_dim=32)
         ssm = None
         if self.ssm is not None:
             ssm = dataclasses.replace(self.ssm, state_dim=16, head_dim=32,
@@ -150,7 +206,7 @@ class ModelConfig:
             n_heads=n_heads, n_kv=n_kv, d_ff=2 * d_model,
             vocab=min(self.vocab, 512), head_dim=0, groups=groups,
             window=min(self.window, 64) if self.window else None,
-            moe=moe, ssm=ssm, rglru=rglru, enc_layers=enc_l,
+            moe=moe, ssm=ssm, rglru=rglru, mla=mla, enc_layers=enc_l,
             enc_seq=16, vision_tokens=8 if self.vision_tokens else 0,
             vision_dim=d_model if self.vision_dim else 0,
             dtype="float32", remat=False)
@@ -218,4 +274,5 @@ def load_all():
     from repro.configs import (  # noqa: F401
         mamba2_780m, recurrentgemma_2b, seamless_m4t_large_v2,
         qwen3_moe_235b_a22b, tinyllama_1_1b, llama_3_2_vision_90b,
-        qwen2_0_5b, qwen3_8b, h2o_danube_3_4b, deepseek_moe_16b, ff_mlp)
+        qwen2_0_5b, qwen3_8b, h2o_danube_3_4b, deepseek_moe_16b,
+        deepseek_v2_lite, ff_mlp)

@@ -126,7 +126,6 @@ from repro import checkpoint as checkpoint_lib, data as data_lib, optim
 from repro.core import faults as faults_lib
 from repro.core import ff, ff_mlp, pff, pff_dag, pff_lm, strategies
 from repro.launch import mesh as mesh_lib
-from repro.models import transformer
 from repro.obs import trace as obs_trace
 
 
@@ -1171,15 +1170,14 @@ class LMExecutor:
     ``make_block_step``/``make_head_step`` programs, the same
     ``chapter_batches`` stream (regenerated locally per node: the
     ``data.Source`` purity contract means training data never crosses
-    the hand-off), and the same global step counters. The jit takes
-    FULL (params, opt) pytrees, so each task assembles one from a
-    per-node replicated template: the live slices (Algorithm-1 frozen
-    prefix params, the task's own block state, the tied-embed head
-    params) arrive through the ``_Handoff`` slots, and every other
-    slice keeps its template (initial) value — provably dead inputs of
-    the jitted program (the extracted outputs depend only on the live
-    slices), so the filler can never affect the weight stream. All
-    assembly is ``device_put`` / ``.at[k].set`` — pure data movement.
+    the hand-off), and the same global step counters. Blocks are
+    addressed by global index over every group of the stack
+    (``pff_lm.block_layout``). The programs take the block trees they
+    read and the task's own state, so a task passes them what the
+    ``_Handoff`` slots deliver: the Algorithm-1 frozen prefix params,
+    the task's own block state, the head params. The executor's
+    programs donate nothing: a slot's tree may be the same buffers the
+    producer holds.
 
     Hand-off traffic per train(k, c): the block's full (params, m, v)
     state streams to the node that trains it in chapter c+1
@@ -1204,9 +1202,6 @@ class LMExecutor:
                 f"are ROADMAP work)")
         if schedule == "sequential" and num_nodes != 1:
             raise ValueError("sequential means num_nodes=1")
-        if len(cfg.groups) != 1:
-            raise ValueError("chapter schedule needs a uniform stack "
-                             f"(one group); got {len(cfg.groups)}")
         self.cfg = cfg
         self.source = source
         self.schedule = schedule
@@ -1217,12 +1212,13 @@ class LMExecutor:
         self.seed = seed
         self.devices = (list(devices)[:num_nodes] if devices is not None
                         else mesh_lib.pff_node_devices(num_nodes))
-        self.n_layers = cfg.groups[0][1]
+        self.n_layers = len(pff_lm.block_layout(cfg))
         self.tied = bool(cfg.tie_embeddings)
         self._head_names = pff_lm.head_param_names(cfg)
-        self._step = pff_lm.make_block_step(cfg, lr=lr, seed=seed)
+        self._step = pff_lm.make_block_step(cfg, lr=lr, seed=seed,
+                                            donate=False)
         self._head_step = pff_lm.make_head_step(
-            cfg, head_lr=lr if head_lr is None else head_lr)
+            cfg, head_lr=lr if head_lr is None else head_lr, donate=False)
         self._data = pff_lm.chapter_batches(source, batch=batch,
                                             steps=steps_per_chapter)
         self._tracer = obs_trace.NOOP
@@ -1236,45 +1232,43 @@ class LMExecutor:
             tr.add_span("task:" + kind, t0, kind=kind, layer=layer,
                         chapter=chapter, node=node, device=_device_id(out))
 
-    def _train_task(self, k, chapter, node):
-        """One per-block chapter task: assemble the full trees on the
-        node, replay ``steps_per_chapter`` sequential block steps with
-        the sequential trainer's global step numbers, publish toward
-        the DAG consumers."""
-        t0 = self._tracer.now()
-        dev = self.devices[node]
-        tp, to = self._tmpl[node]
-        gp = tp["groups"][0]
-        for j in range(k):
+    def _blocks_on(self, node, chapter, n):
+        """The chapter-``chapter`` params of blocks < n on ``node``."""
+        out = []
+        for j in range(n):
             # Algorithm-1 frozen prefix: block j at chapter `chapter`
             assert self._ver[j] == chapter, (j, self._ver[j], chapter)
-            pj = self._handoff.take(("params", j), node, chapter,
-                                    self._blk[j][0])
-            gp = pff_lm._set_unit(gp, pj, j)
+            out.append(self._handoff.take(("params", j), node, chapter,
+                                          self._blk[j][0]))
+        return tuple(out)
+
+    def _embed_on(self, node):
+        """The input table a block task on ``node`` reads: the untied
+        (never trained) table, or — tied — the post-head one that
+        head(chapter-1) produced (the head_feedback edge)."""
+        if not self.tied:
+            return self._embed[node]
+        return self._handoff.take(("headp",), node, self._head_ver,
+                                  self._head[0])["embed"]
+
+    def _train_task(self, k, chapter, node):
+        """One per-block chapter task: take the frozen prefix and the
+        block's state on the node, replay ``steps_per_chapter``
+        sequential block steps with the sequential trainer's global
+        step numbers, publish toward the DAG consumers."""
+        t0 = self._tracer.now()
+        dev = self.devices[node]
+        prefix = self._blocks_on(node, chapter, k)
         up, um, uv = self._handoff.take(("state", k), node, self._ver[k],
                                         self._blk[k])
-        gp = pff_lm._set_unit(gp, up, k)
-        gm = pff_lm._set_unit(to["m"]["groups"][0], um, k)
-        gv = pff_lm._set_unit(to["v"]["groups"][0], uv, k)
-        p = dict(tp)
-        p["groups"] = (gp,)
-        if self.tied:
-            # head_feedback edge: the embed table this task reads is
-            # the one head(chapter-1) produced
-            hp = self._handoff.take(("headp",), node, self._head_ver,
-                                    self._head[0])
-            for name in self._head_names:
-                p[name] = hp[name]
-        opt = {"m": {**to["m"], "groups": (gm,)},
-               "v": {**to["v"], "groups": (gv,)}}
+        embed = self._embed_on(node)
         base = (chapter * self.n_layers + k) * self.steps_per_chapter
         last = None
         for s, batch in enumerate(self._data(chapter, k)):
-            p, opt, last = self._step(p, opt, jax.device_put(batch, dev),
-                                      k, base + s + 1)
-        self._blk[k] = (pff_lm._slice_unit(p["groups"][0], k),
-                        pff_lm._slice_unit(opt["m"]["groups"][0], k),
-                        pff_lm._slice_unit(opt["v"]["groups"][0], k))
+            up, um, uv, last, _ = self._step(
+                embed, prefix, up, um, uv, jax.device_put(batch, dev), k,
+                base + s + 1)
+        self._blk[k] = (up, um, uv)
         self._ver[k] = chapter
         nxt, param_nodes = pff_dag.handoff_targets(
             self.schedule, self.num_nodes, n_layers=self.n_layers,
@@ -1294,29 +1288,17 @@ class LMExecutor:
         make_head_step``), head state published toward chapter c+1."""
         t0 = self._tracer.now()
         dev = self.devices[node]
-        tp, to = self._tmpl[node]
-        gp = tp["groups"][0]
-        for j in range(self.n_layers):
-            assert self._ver[j] == chapter, (j, self._ver[j], chapter)
-            pj = self._handoff.take(("params", j), node, chapter,
-                                    self._blk[j][0])
-            gp = pff_lm._set_unit(gp, pj, j)
+        blocks_p = self._blocks_on(node, chapter, self.n_layers)
         hp, hm, hv = self._handoff.take(("head",), node, self._head_ver,
                                         self._head)
-        p = dict(tp)
-        p["groups"] = (gp,)
-        m, v = dict(to["m"]), dict(to["v"])
-        for name in self._head_names:
-            p[name], m[name], v[name] = hp[name], hm[name], hv[name]
-        opt = {"m": m, "v": v}
+        embed = None if self.tied else self._embed[node]
         base = chapter * self.steps_per_chapter
         last = None
         for s, batch in enumerate(self._data(chapter, self.n_layers)):
-            p, opt, last = self._head_step(
-                p, opt, jax.device_put(batch, dev), base + s + 1)
-        self._head = ({n: p[n] for n in self._head_names},
-                      {n: opt["m"][n] for n in self._head_names},
-                      {n: opt["v"][n] for n in self._head_names})
+            hp, hm, hv, last = self._head_step(
+                embed, blocks_p, hp, hm, hv, jax.device_put(batch, dev),
+                base + s + 1)
+        self._head = (hp, hm, hv)
         self._head_ver = chapter
         if chapter + 1 < self.chapters:
             nh = pff_dag.head_node_of(self.schedule, self.num_nodes,
@@ -1349,17 +1331,12 @@ class LMExecutor:
         self._block = profile or (tracer.enabled and tracer.block_tasks)
         timeline = tracer.enabled and self._block
         span0 = tracer.span_count()
-        params = transformer.init(jax.random.PRNGKey(self.seed), cfg)
-        opt = optim.adam_init(params)
-        gp, gm, gv = (params["groups"][0], opt["m"]["groups"][0],
-                      opt["v"]["groups"][0])
+        bp, rest = pff_lm.init_state(cfg, self.seed)
         # canonical state partition: per-block unit slices + head subset
-        self._blk = [(pff_lm._slice_unit(gp, k),
-                      pff_lm._slice_unit(gm, k),
-                      pff_lm._slice_unit(gv, k))
-                     for k in range(self.n_layers)]
-        self._head = tuple({n: t[n] for n in self._head_names}
-                           for t in (params, opt["m"], opt["v"]))
+        self._blk = [(u, *(optim.adam_init(u)["m"] for _ in range(2)))
+                     for u in bp]
+        hp = {n: rest.pop(n) for n in self._head_names}
+        self._head = (hp, *(optim.adam_init(hp)["m"] for _ in range(2)))
         self._ver = [-1] * self.n_layers
         self._head_ver = -1
         self._handoff = _Handoff(self.devices, self.overlap,
@@ -1367,10 +1344,10 @@ class LMExecutor:
         t_start = time.perf_counter()
         t_trace0 = tracer.now()
         # initial placement rides the timed window (like PFFExecutor):
-        # one full (params, opt) template per node — dead-slice filler
-        # the per-task assembly overwrites with the live hand-off bits
-        self._tmpl = {node: jax.device_put((params, opt), dev)
-                      for node, dev in enumerate(self.devices)}
+        # the untied input table on every node
+        if not self.tied:
+            self._embed = {node: jax.device_put(rest["embed"], dev)
+                           for node, dev in enumerate(self.devices)}
         for c in range(self.chapters):
             for k in range(self.n_layers):
                 self._train_task(k, c, pff_dag.node_of(
@@ -1392,14 +1369,9 @@ class LMExecutor:
         # reassemble the canonical full params pytree on node 0 —
         # exactly the tree the sequential trainer returns
         dev0 = self.devices[0]
-        fgp = jax.device_put(params["groups"][0], dev0)
-        for k in range(self.n_layers):
-            fgp = pff_lm._set_unit(
-                fgp, jax.device_put(self._blk[k][0], dev0), k)
-        final = dict(jax.device_put(params, dev0))
-        final["groups"] = (fgp,)
-        for name in self._head_names:
-            final[name] = jax.device_put(self._head[0][name], dev0)
+        final = pff_lm.join_params(
+            cfg, [jax.device_put(b[0], dev0) for b in self._blk],
+            jax.device_put({**rest, **self._head[0]}, dev0))
         records = node_busy = None
         if timeline:
             records, node_busy = _records_from_spans(tracer, span0,
@@ -1543,16 +1515,23 @@ _MATRIX = (
 _AB_CASES = (1, 3, 6)
 
 
-def _lm_case_setup(n_blocks, tied, *, seq_len=16):
+def _lm_case_setup(n_blocks, tied, arch="qwen2-0.5b", *, seq_len=16):
     """The (cfg, source) every LM selftest case trains: a tiny
     qwen2-0.5b-shaped stack over the real-text BPE source — the same
     construction ``benchmarks/lm_exec.py`` and ``tests/test_pff_lm.py``
-    use."""
+    use — or a tiny deepseek-v2-lite stack of two groups (one dense MLA
+    block, then MLA + MoE blocks holding 2 of the 4 experts)."""
     from repro.configs import get_config
 
-    cfg = get_config("qwen2-0.5b").reduced()
-    cfg = dataclasses.replace(cfg, num_layers=n_blocks,
-                              groups=((("attn",), n_blocks),))
+    cfg = get_config(arch).reduced()
+    if arch == "deepseek-v2-lite":
+        cfg = dataclasses.replace(
+            cfg, num_layers=n_blocks,
+            groups=((("mla_dense",), 1), (("mla_moe",), n_blocks - 1)),
+            moe=dataclasses.replace(cfg.moe, held=2, shard=1))
+    else:
+        cfg = dataclasses.replace(cfg, num_layers=n_blocks,
+                                  groups=((("attn",), n_blocks),))
     if not tied:
         cfg = dataclasses.replace(cfg, tie_embeddings=False)
     source = data_lib.text_source(vocab=cfg.vocab, seq_len=seq_len,
@@ -1560,14 +1539,14 @@ def _lm_case_setup(n_blocks, tied, *, seq_len=16):
     return cfg, source
 
 
-def _lm_check_case(schedule, nodes, n_blocks, chapters, steps, tied, *,
-                   check_overlap_ab=False):
+def _lm_check_case(schedule, nodes, n_blocks, chapters, steps, tied,
+                   arch="qwen2-0.5b", *, check_overlap_ab=False):
     """Trains one LM config both ways — through ``api.fit`` — and
     returns failure strings (empty = the executor reproduced the
     sequential ``train_chapters`` weight stream bit-exactly)."""
     from repro import api
 
-    cfg, source = _lm_case_setup(n_blocks, tied)
+    cfg, source = _lm_case_setup(n_blocks, tied, arch)
     kw = dict(chapters=chapters, steps_per_chapter=steps, batch=4,
               lr=1e-3)
     ref = api.fit(cfg, source, backend="sequential", **kw)
@@ -1593,7 +1572,7 @@ def _lm_check_case(schedule, nodes, n_blocks, chapters, steps, tied, *,
                             f"prefetched slot ({stats_on})")
         print(f"  lm overlap A/B {schedule}: on={stats_on} "
               f"off={stats_off}")
-    print(f"devices={len(jax.devices())} lm schedule={schedule} "
+    print(f"devices={len(jax.devices())} lm {arch} schedule={schedule} "
           f"nodes={nodes} blocks={n_blocks} tied={tied}: "
           f"exec ce={res.eval_ce:.4f} seq ce={ref.eval_ce:.4f} "
           f"makespan={res.makespan:.2f}s -> "
@@ -1601,16 +1580,19 @@ def _lm_check_case(schedule, nodes, n_blocks, chapters, steps, tied, *,
     return failures
 
 
-# (schedule, nodes, n_blocks, chapters, steps_per_chapter, tied)
+# (schedule, nodes, n_blocks, chapters, steps_per_chapter, tied[, arch])
 # Row 1/2: the acceptance-criteria pair — both paper schedules, N=4
 # faked devices, tied embeddings (the head_feedback edge: every block
 # task must see the post-head embed table), with the overlap A/B gate.
 # Row 3: untied head (lm_head path) + nodes not dividing the block
 # count, so the single_layer round-robin wraps.
+# Row 4: a stack of two groups (deepseek-v2-lite: a dense MLA block,
+# then MLA + held-expert MoE blocks), blocks addressed across groups.
 _LM_MATRIX = (
     ("all_layers", 4, 4, 3, 2, True),
     ("single_layer", 4, 4, 3, 2, True),
     ("single_layer", 2, 3, 2, 2, False),
+    ("all_layers", 2, 3, 2, 1, False, "deepseek-v2-lite"),
 )
 _LM_AB_CASES = (0, 1)
 

@@ -22,6 +22,7 @@ from typing import List
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro import optim
 from repro.core import ff
@@ -29,80 +30,138 @@ from repro.core import train as train_lib
 from repro.core.pff import TaskRecord
 from repro.models import blocks, common, transformer
 from repro.models.mlp import NO_DIST
+from repro.obs import trace as obs_trace
 
 
-def _slice_unit(tree, k):
-    return jax.tree.map(lambda a: a[k], tree)
+def block_layout(cfg):
+    """[(group, unit, pattern)] of every chapter block in stack order:
+    block k of the chapter schedule is unit ``unit`` of group ``group``
+    (one pattern instance), so a stack of several groups (a leading
+    dense layer, then the MoE layers) is addressed by global index."""
+    return [(gi, u, pattern) for gi, (pattern, repeat)
+            in enumerate(cfg.groups) for u in range(repeat)]
 
 
-def _set_unit(tree, unit, k):
-    return jax.tree.map(lambda a, u: a.at[k].set(u), tree, unit)
+def split_params(cfg, tree):
+    """(blocks, rest) of a params-shaped tree (the params, or Adam's m
+    or v): the per-block unit trees in ``block_layout`` order, and the
+    entries outside the groups."""
+    blocks = [jax.tree.map(lambda a, u=u: a[u], tree["groups"][gi])
+              for gi, u, _ in block_layout(cfg)]
+    return blocks, {k: v for k, v in tree.items() if k != "groups"}
 
 
-def make_block_step(cfg, *, lr=1e-3, seed=0, theta=None):
-    """Returns step(params, opt, batch, block_idx, step_no) that updates
-    ONLY block ``block_idx`` (plus nothing else — the paper's per-node
-    task). Single-group architectures (uniform stacks)."""
-    assert len(cfg.groups) == 1, "chapter schedule needs a uniform stack"
-    pattern, repeat = cfg.groups[0]
+def join_params(cfg, blocks, rest):
+    """The inverse of ``split_params``: the model's params pytree."""
+    groups, i = [], 0
+    for _, repeat in cfg.groups:
+        groups.append(jax.tree.map(lambda *a: jnp.stack(a),
+                                   *blocks[i:i + repeat]))
+        i += repeat
+    return {**rest, "groups": tuple(groups)}
+
+
+@functools.lru_cache(maxsize=8)
+def _init_program(cfg):
+    return jax.jit(lambda key: split_params(cfg, transformer.init(key, cfg)))
+
+
+def init_state(cfg, seed):
+    """(blocks, rest) of ``transformer.init`` at ``seed``, split on the
+    device in one program, so the stacked tree is never held beside its
+    split."""
+    return _init_program(cfg)(jax.random.PRNGKey(seed))
+
+
+def _unit_forward(cfg, pattern, unit_p, x):
+    """x through one frozen chapter block (a pattern instance)."""
+    for kind, bp in zip(pattern, unit_p):
+        x, _ = blocks.block_apply(bp, cfg, kind, x,
+                                  {"causal": True, "dist": NO_DIST})
+    return x
+
+
+def _forward_program(cfg, name):
+    """A jitted frozen forward through one chapter block, one program
+    per block pattern, shared by every block of that pattern: a step
+    runs its prefix as a chain of these rather than compiling one
+    forward per prefix length."""
+    fn = lambda unit_p, x, pattern: _unit_forward(cfg, pattern, unit_p, x)
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, static_argnames=("pattern",))
+
+
+@functools.lru_cache(maxsize=8)
+def make_block_step(cfg, *, lr=1e-3, seed=0, theta=None, donate=True):
+    """Returns ``step(embed, prefix, unit_p, unit_m, unit_v, batch,
+    block_idx, step_no)`` -> ``(unit_p, unit_m, unit_v, loss, counts)``:
+    one FF step of block ``block_idx`` alone (the paper's per-node task)
+    on the outputs of the frozen ``prefix`` (the trees of blocks <
+    block_idx). Three jitted programs, all named ``lm_block_step*`` in
+    a trace: the inputs (the embedded [positives; corrupted negatives]
+    of the step), the frozen forward through each prefix block, and the
+    block's own loss, gradient and Adam update. Only the block's own
+    state is an output of the last, and with ``donate`` its buffers
+    are donated to it, so the stacked state is held once. ``counts``
+    (MoE layers, held experts) are the step's assignments to the held
+    experts of each dropless MoE layer of the block (0 rows for a block
+    without one). One set of programs per (cfg, lr, seed, theta,
+    donate), kept: every job of a configuration runs the programs the
+    first one compiled."""
+    layout = block_layout(cfg)
     theta = theta if theta is not None else cfg.ff.theta
+    aux_w = cfg.moe.router_aux_weight if cfg.moe is not None else 0.0
 
-    @functools.partial(jax.jit, static_argnames=("block_idx",))
-    def step(params, opt_state, batch, block_idx, step_no):
-        assert 0 <= block_idx < repeat, (block_idx, repeat)
+    @jax.jit
+    def lm_block_step_inputs(embed, batch, step_no):
         tokens = batch["tokens"][:, :-1]
         B = tokens.shape[0]
         key = jax.random.fold_in(jax.random.PRNGKey(seed), step_no)
         neg = ff.corrupt_tokens(key, tokens, cfg.vocab)
-        x = jnp.take(params["embed"],
-                     jnp.concatenate([tokens, neg], axis=0), axis=0)
+        x = jnp.take(embed, jnp.concatenate([tokens, neg], axis=0), axis=0)
         is_pos = jnp.concatenate(
             [jnp.ones((B,)), jnp.zeros((B,))]).astype(jnp.float32)
+        return x, is_pos
+
+    forward = _forward_program(cfg, "lm_block_step_forward")
+
+    def lm_block_step(x, is_pos, unit_p, unit_m, unit_v, step_no, pattern):
         ctx = {"causal": True, "dist": NO_DIST}
 
-        gp = params["groups"][0]
-
-        # frozen forward through blocks < block_idx
-        def fwd_body(carry, unit_p):
-            h = carry
-            for kind, bp in zip(pattern, unit_p):
-                h, _ = blocks.block_apply(bp, cfg, kind, h, ctx)
-            return h, None
-
-        if block_idx > 0:
-            prefix = jax.tree.map(lambda a: a[:block_idx], gp)
-            x, _ = jax.lax.scan(fwd_body, x, prefix)
-        x = jax.lax.stop_gradient(x)
-
-        unit_p = _slice_unit(gp, block_idx)
-        unit_m = _slice_unit(opt_state["m"]["groups"][0], block_idx)
-        unit_v = _slice_unit(opt_state["v"]["groups"][0], block_idx)
-
         def loss_fn(up):
+            stats = []
             h = x
             total = jnp.zeros(())
             for kind, bp in zip(pattern, up):
                 h_sg = jax.lax.stop_gradient(h)
-                y, moe_aux = blocks.block_apply(bp, cfg, kind, h_sg, ctx)
+                y, moe_aux = blocks.block_apply(
+                    bp, cfg, kind, h_sg, {**ctx, "moe_stats": stats})
                 g = ff.mean_goodness(y - h_sg)
                 total = total + ff.ff_loss_masked(g, is_pos, theta) \
-                    + 0.01 * moe_aux
+                    + aux_w * moe_aux
                 h = y
-            return total
+            counts = (jnp.stack(stats) if stats
+                      else jnp.zeros((0, 0), jnp.int32))
+            return total, counts
 
-        loss, grads = jax.value_and_grad(loss_fn)(unit_p)
-        new_unit, st = optim.adam_update(
-            unit_p, grads, {"m": unit_m, "v": unit_v}, lr=lr,
-            step=step_no)
-        new_params = dict(params)
-        new_params["groups"] = (_set_unit(gp, new_unit, block_idx),)
-        new_m = dict(opt_state["m"])
-        new_v = dict(opt_state["v"])
-        new_m["groups"] = (_set_unit(opt_state["m"]["groups"][0],
-                                     st["m"], block_idx),)
-        new_v["groups"] = (_set_unit(opt_state["v"]["groups"][0],
-                                     st["v"], block_idx),)
-        return new_params, {"m": new_m, "v": new_v}, loss
+        (loss, counts), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(unit_p)
+        new_p, st = optim.adam_update(
+            unit_p, grads, {"m": unit_m, "v": unit_v}, lr=lr, step=step_no)
+        return new_p, st["m"], st["v"], loss, counts
+
+    train = jax.jit(lm_block_step, static_argnames=("pattern",),
+                    donate_argnames=(("unit_p", "unit_m", "unit_v")
+                                     if donate else ()))
+
+    def step(embed, prefix, unit_p, unit_m, unit_v, batch, block_idx,
+             step_no):
+        assert len(prefix) == block_idx < len(layout), (block_idx,)
+        x, is_pos = lm_block_step_inputs(embed, batch, step_no)
+        for j, up in enumerate(prefix):
+            x = forward(up, x, pattern=layout[j][2])
+        return train(x, is_pos, unit_p, unit_m, unit_v, step_no,
+                     pattern=layout[block_idx][2])
 
     return step
 
@@ -115,32 +174,29 @@ def head_param_names(cfg):
     return ("final_norm", "embed" if cfg.tie_embeddings else "lm_head")
 
 
-def make_head_step(cfg, *, head_lr=1e-3):
-    """Returns head_step(params, opt, batch, step_no) — the per-chapter
-    softmax-head task (DAG ``Task("head", n_layers, c)``): a frozen
-    forward through ALL blocks, then local CE on the head subset only
-    (``head_param_names``). Mirrors the joint step's head treatment:
-    features are stop-gradded, so a tied table receives the CE grad
-    only through the unembed."""
-    assert len(cfg.groups) == 1, "chapter schedule needs a uniform stack"
-    pattern, _ = cfg.groups[0]
-    names = head_param_names(cfg)
+@functools.lru_cache(maxsize=8)
+def make_head_step(cfg, *, head_lr=1e-3, donate=True):
+    """Returns ``head_step(embed, blocks, hp, hm, hv, batch, step_no)``
+    -> ``(hp, hm, hv, loss)`` — the per-chapter softmax-head task (DAG
+    ``Task("head", n_blocks, c)``): a frozen forward through ALL blocks
+    (``blocks``, every block's tree), then local CE on the head subset
+    ``hp`` only (``head_param_names``; its Adam moments ``hm``, ``hv``,
+    donated with it under ``donate``). ``embed`` is the input table of
+    an untied model (None where tied: the table is ``hp["embed"]``).
+    Mirrors the joint step's head treatment: features are
+    stop-gradded, so a tied table receives the CE grad only through the
+    unembed. Jitted programs named ``lm_head_step*``: the embedding,
+    the frozen forward per block pattern, and the head's CE and Adam."""
+    layout = block_layout(cfg)
 
     @jax.jit
-    def head_step(params, opt_state, batch, step_no):
-        tokens = batch["tokens"]
-        inp, labels = tokens[:, :-1], tokens[:, 1:]
-        x = jnp.take(params["embed"], inp, axis=0)
-        ctx = {"causal": True, "dist": NO_DIST}
+    def lm_head_step_inputs(table, batch):
+        return jnp.take(table, batch["tokens"][:, :-1], axis=0)
 
-        def fwd_body(carry, unit_p):
-            h = carry
-            for kind, bp in zip(pattern, unit_p):
-                h, _ = blocks.block_apply(bp, cfg, kind, h, ctx)
-            return h, None
+    forward = _forward_program(cfg, "lm_head_step_forward")
 
-        x, _ = jax.lax.scan(fwd_body, x, params["groups"][0])
-        x = jax.lax.stop_gradient(x)
+    def lm_head_step(x, hp, hm, hv, batch, step_no):
+        labels = batch["tokens"][:, 1:]
 
         def head_loss(hp):
             h = common.rms_norm(x, hp["final_norm"], cfg.norm_eps)
@@ -150,21 +206,20 @@ def make_head_step(cfg, *, head_lr=1e-3):
                                           softcap=cfg.logit_softcap)
             return total / labels.size
 
-        hp = {k: params[k] for k in names}
         loss, grads = jax.value_and_grad(head_loss)(hp)
-        new_hp, st = optim.adam_update(
-            hp, grads,
-            {"m": {k: opt_state["m"][k] for k in names},
-             "v": {k: opt_state["v"][k] for k in names}},
-            lr=head_lr, step=step_no)
-        new_params = dict(params)
-        new_m = dict(opt_state["m"])
-        new_v = dict(opt_state["v"])
-        for k in names:
-            new_params[k] = new_hp[k]
-            new_m[k] = st["m"][k]
-            new_v[k] = st["v"][k]
-        return new_params, {"m": new_m, "v": new_v}, loss
+        new_hp, st = optim.adam_update(hp, grads, {"m": hm, "v": hv},
+                                       lr=head_lr, step=step_no)
+        return new_hp, st["m"], st["v"], loss
+
+    train = jax.jit(lm_head_step,
+                    donate_argnames=("hp", "hm", "hv") if donate else ())
+
+    def head_step(embed, blocks_p, hp, hm, hv, batch, step_no):
+        x = lm_head_step_inputs(hp["embed"] if cfg.tie_embeddings
+                                else embed, batch)
+        for j, up in enumerate(blocks_p):
+            x = forward(up, x, pattern=layout[j][2])
+        return train(x, hp, hm, hv, batch, step_no)
 
     return head_step
 
@@ -184,50 +239,90 @@ def chapter_batches(source, *, batch, steps):
     return data_iter
 
 
+def load_attrs(counts):
+    """Span attrs of a MoE block task from its steps' ``counts``:
+    ``routed``, the assignments to held experts, and
+    ``load_max_over_mean`` over the held experts."""
+    per_expert = np.asarray(jnp.stack(counts)).sum(axis=(0, 1))
+    return {"routed": int(per_expert.sum()),
+            "load_max_over_mean": float(per_expert.max()
+                                        / max(per_expert.mean(), 1e-9))}
+
+
 def train_chapters(cfg, data_iter_fn, *, chapters, steps_per_chapter,
-                   lr=1e-3, head_lr=None, seed=0):
-    """Runs the chapter schedule; returns (params, records, ff_losses).
+                   lr=1e-3, head_lr=None, seed=0, tracer=obs_trace.NOOP):
+    """Runs the chapter schedule; returns (params, records, losses,
+    head_losses).
 
     data_iter_fn(chapter, block) -> iterable of batches for that task;
     the per-chapter head task draws ``data_iter_fn(c, n_blocks)``.
-    The LM head (final_norm + lm_head/embed-as-softmax) trains at the
-    end of each chapter, like the paper's softmax layer, at ``head_lr``
-    (default: ``lr``); its ``TaskRecord("head", n_blocks, c)`` rides
-    the same record stream the simulator consumes. ``ff_losses`` stays
-    train-task-only (one FF loss per block task, the historical
-    contract) — head CE is observable through ``train.eval_ce``.
+    Blocks are addressed by global index (``block_layout``), across
+    every group of the stack. The LM head (final_norm +
+    lm_head/embed-as-softmax) trains at the end of each chapter, like
+    the paper's softmax layer, at ``head_lr`` (default: ``lr``); its
+    ``TaskRecord("head", n_blocks, c)`` rides the same record stream
+    the simulator consumes. ``losses`` holds one device array per block
+    task, its steps' FF losses; ``head_losses`` one per head task, its
+    steps' CE. Neither is read back here: a task waits for its state
+    alone, for its record.
+
+    Spans (``tracer``): ``fit:init``; per task ``task:train`` (layer,
+    chapter, steps, kind; on a traced MoE block also ``routed`` and
+    ``load_max_over_mean``, ``load_attrs``) with ``task:wait`` inside,
+    and ``task:head``.
     """
-    key = jax.random.PRNGKey(seed)
-    params = transformer.init(key, cfg)
-    opt = optim.adam_init(params)
+    layout = block_layout(cfg)
+    nb = len(layout)
+    with tracer.span("fit:init"):
+        bp, rest = init_state(cfg, seed)
+        heads = head_param_names(cfg)
+        hp = {k: rest.pop(k) for k in heads}
+        bm, bv = ([optim.adam_init(u)["m"] for u in bp] for _ in range(2))
+        hm, hv = (optim.adam_init(hp)["m"] for _ in range(2))
+        embed = None if cfg.tie_embeddings else rest["embed"]
+        jax.block_until_ready((bp, bm, bv, hm, hv))
     step = make_block_step(cfg, lr=lr, seed=seed)
     head_step = make_head_step(
         cfg, head_lr=lr if head_lr is None else head_lr)
-    _, repeat = cfg.groups[0][0], cfg.groups[0][1]
     records: List[TaskRecord] = []
-    losses = []
+    losses, head_losses = [], []
     n = 0
     n_head = 0
     for c in range(chapters):
-        for k in range(repeat):
+        for k, (_, _, pattern) in enumerate(layout):
+            with tracer.span("task:train", layer=k, chapter=c,
+                             steps=steps_per_chapter,
+                             kind="+".join(pattern)) as attrs:
+                t0 = time.perf_counter()
+                step_losses, counts = [], []
+                for batch in data_iter_fn(c, k):
+                    n += 1
+                    bp[k], bm[k], bv[k], loss, cnt = step(
+                        hp["embed"] if embed is None else embed,
+                        tuple(bp[:k]), bp[k], bm[k], bv[k], batch, k, n)
+                    step_losses.append(loss)
+                    counts.append(cnt)
+                with obs_trace.annotate("task:wait"):
+                    jax.block_until_ready(bp[k])
+                losses.append(jnp.stack(step_losses))
+                if tracer.enabled and counts[0].size:
+                    attrs.update(load_attrs(counts))
+                records.append(TaskRecord("train", k, c,
+                                          time.perf_counter() - t0))
+        with tracer.span("task:head", chapter=c):
             t0 = time.perf_counter()
-            last = None
-            for batch in data_iter_fn(c, k):
-                n += 1
-                params, opt, last = step(params, opt, batch, k, n)
-            jax.block_until_ready(last)
-            records.append(TaskRecord("train", k, c,
+            step_losses = []
+            for batch in data_iter_fn(c, nb):
+                n_head += 1
+                hp, hm, hv, loss = head_step(embed, tuple(bp), hp, hm, hv,
+                                             batch, n_head)
+                step_losses.append(loss)
+            jax.block_until_ready(hp)
+            head_losses.append(jnp.stack(step_losses))
+            records.append(TaskRecord("head", nb, c,
                                       time.perf_counter() - t0))
-            losses.append(float(last))
-        t0 = time.perf_counter()
-        last = None
-        for batch in data_iter_fn(c, repeat):
-            n_head += 1
-            params, opt, last = head_step(params, opt, batch, n_head)
-        jax.block_until_ready(last)
-        records.append(TaskRecord("head", repeat, c,
-                                  time.perf_counter() - t0))
-    return params, records, losses
+    del bm, bv, hm, hv
+    return join_params(cfg, bp, {**rest, **hp}), records, losses, head_losses
 
 
 def lm_params_bit_equal(a, b) -> bool:

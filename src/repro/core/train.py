@@ -348,10 +348,30 @@ def make_bp_train_step(cfg, *, dist=NO_DIST, lr=1e-3):
 # Eval
 # ---------------------------------------------------------------------------
 
+EVAL_ROWS = 2      # sequences a chunk of eval_ce carries through the model
+
+
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def eval_ce(params, cfg, tokens, aux=None):
-    inp, labels = tokens[:, :-1], tokens[:, 1:]
-    logits, _ = transformer.forward(params, cfg, inp, aux=aux, remat=False)
-    lp = jax.nn.log_softmax(logits)
-    ce = -jnp.take_along_axis(lp, labels[..., None], axis=-1)[..., 0]
-    return jnp.mean(ce)
+    """Mean next-token CE over ``tokens`` (B, S+1), EVAL_ROWS sequences
+    at a time through the model (B a multiple of it, or B alone), and
+    the logits a sequence chunk at a time (``_ce_chunked``): neither the
+    whole batch's activations nor its (B, S, V) logits are held."""
+    B = tokens.shape[0]
+    rows = EVAL_ROWS if B % EVAL_ROWS == 0 else B
+
+    def chunk(args):
+        tok, ax = args
+        inp, labels = tok[:, :-1], tok[:, 1:]
+        x, _ = transformer.hidden(params, cfg, inp, aux=ax, remat=False)
+        h = common.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        w = params["embed"] if cfg.tie_embeddings else params["lm_head"].T
+        if cfg.padded_vocab != cfg.vocab:
+            w = w[:cfg.vocab]
+        return _ce_chunked(h, w, labels, jnp.ones(labels.shape, jnp.float32),
+                           softcap=cfg.logit_softcap)
+
+    split = lambda a: None if a is None else a.reshape(
+        (B // rows, rows) + a.shape[1:])
+    total = jnp.sum(jax.lax.map(chunk, (split(tokens), split(aux))))
+    return total / (B * (tokens.shape[1] - 1))

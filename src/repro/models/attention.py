@@ -54,11 +54,14 @@ def _mask(q_pos, k_pos, causal, window):
 
 def chunked_attention(q, k, v, *, causal=True, window=None, q_offset=0,
                       k_offset=0, q_chunk=512, k_chunk=1024,
-                      causal_skip=False):
-    """q: (B, Sq, H, hd); k, v: (B, Sk, KV, hd). Returns (B, Sq, H, hd).
+                      causal_skip=False, scale=None):
+    """q: (B, Sq, H, hd); k: (B, Sk, KV, hd); v: (B, Sk, KV, dv).
+    Returns (B, Sq, H, dv). ``scale`` defaults to hd ** -0.5.
 
     Online-softmax over kv chunks, scanned over q chunks: peak score
     buffer is (B, H, q_chunk, k_chunk) regardless of sequence length.
+    Each q chunk is rematerialised in the backward pass: kept, its
+    chunk pairs' scores would hold the whole (B, H, Sq, Sk) matrix.
 
     ``causal_skip`` (a §Perf optimization, off by default): instead of
     the dense nq x nk double scan, enumerate only the VISIBLE (q, k)
@@ -69,9 +72,11 @@ def chunked_attention(q, k, v, *, causal=True, window=None, q_offset=0,
     if (causal_skip and isinstance(q_offset, int) and q_offset == 0
             and isinstance(k_offset, int) and k_offset == 0):
         return _triangle_attention(q, k, v, causal=causal, window=window,
-                                   q_chunk=q_chunk, k_chunk=k_chunk)
+                                   q_chunk=q_chunk, k_chunk=k_chunk,
+                                   scale=scale)
     B, Sq, H, hd = q.shape
     _, Sk, KV, _ = k.shape
+    dv = v.shape[-1]
     G = H // KV
     q_chunk = min(q_chunk, Sq)
     k_chunk = min(k_chunk, Sk)
@@ -80,12 +85,12 @@ def chunked_attention(q, k, v, *, causal=True, window=None, q_offset=0,
     if Sk % k_chunk:
         k_chunk = Sk
     nq, nk = Sq // q_chunk, Sk // k_chunk
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
 
     # (nq, B, qc, KV, G, hd)
     qs = q.reshape(B, nq, q_chunk, KV, G, hd).transpose(1, 0, 2, 3, 4, 5)
     ks = k.reshape(B, nk, k_chunk, KV, hd).transpose(1, 0, 2, 3, 4)
-    vs = v.reshape(B, nk, k_chunk, KV, hd).transpose(1, 0, 2, 3, 4)
+    vs = v.reshape(B, nk, k_chunk, KV, dv).transpose(1, 0, 2, 3, 4)
     q_positions = q_offset + jnp.arange(Sq, dtype=jnp.int32)
     k_positions = k_offset + jnp.arange(Sk, dtype=jnp.int32)
 
@@ -111,20 +116,21 @@ def chunked_attention(q, k, v, *, causal=True, window=None, q_offset=0,
         m0, l0, a0 = (_varying_like(c, qc, ks, vs) for c in (
             jnp.full((B, KV, G, q_chunk), NEG_INF, jnp.float32),
             jnp.zeros((B, KV, G, q_chunk), jnp.float32),
-            jnp.zeros((B, KV, G, q_chunk, hd), jnp.float32)))
+            jnp.zeros((B, KV, G, q_chunk, dv), jnp.float32)))
         (m, l, acc), _ = jax.lax.scan(
             k_body, (m0, l0, a0), (ks, vs, k_positions.reshape(nk, k_chunk)))
         l = jnp.maximum(l, 1e-30)
-        out = (acc / l[..., None]).astype(q.dtype)     # (B, KV, G, qc, hd)
-        return None, out.transpose(0, 3, 1, 2, 4)      # (B, qc, KV, G, hd)
+        out = (acc / l[..., None]).astype(q.dtype)     # (B, KV, G, qc, dv)
+        return None, out.transpose(0, 3, 1, 2, 4)      # (B, qc, KV, G, dv)
 
     _, outs = jax.lax.scan(
-        q_body, None, (qs, q_positions.reshape(nq, q_chunk)))
-    # (nq, B, qc, KV, G, hd) -> (B, Sq, H, hd)
-    return outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, Sq, H, hd)
+        jax.checkpoint(q_body), None, (qs, q_positions.reshape(nq, q_chunk)))
+    # (nq, B, qc, KV, G, dv) -> (B, Sq, H, dv)
+    return outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, Sq, H, dv)
 
 
-def _triangle_attention(q, k, v, *, causal, window, q_chunk, k_chunk):
+def _triangle_attention(q, k, v, *, causal, window, q_chunk, k_chunk,
+                        scale=None):
     """Visible-chunk-pair enumeration (static) + flat scan.
 
     Carries full (nq, ...) online-softmax tables; each step updates one
@@ -132,6 +138,7 @@ def _triangle_attention(q, k, v, *, causal, window, q_chunk, k_chunk):
     """
     B, Sq, H, hd = q.shape
     _, Sk, KV, _ = k.shape
+    dv = v.shape[-1]
     G = H // KV
     qc = min(q_chunk, Sq)
     kc = min(k_chunk, Sk)
@@ -140,7 +147,7 @@ def _triangle_attention(q, k, v, *, causal, window, q_chunk, k_chunk):
     if Sk % kc:
         kc = Sk
     nq, nk = Sq // qc, Sk // kc
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
 
     pairs = []
     for i in range(nq):
@@ -156,7 +163,7 @@ def _triangle_attention(q, k, v, *, causal, window, q_chunk, k_chunk):
 
     qs = q.reshape(B, nq, qc, KV, G, hd).transpose(1, 0, 2, 3, 4, 5)
     ks = k.reshape(B, nk, kc, KV, hd).transpose(1, 0, 2, 3, 4)
-    vs = v.reshape(B, nk, kc, KV, hd).transpose(1, 0, 2, 3, 4)
+    vs = v.reshape(B, nk, kc, KV, dv).transpose(1, 0, 2, 3, 4)
 
     def body(carry, pair):
         m_t, l_t, acc_t = carry                 # (nq, B, KV, G, qc[, hd])
@@ -188,24 +195,27 @@ def _triangle_attention(q, k, v, *, causal, window, q_chunk, k_chunk):
     m0, l0, a0 = (_varying_like(c, qs, ks, vs) for c in (
         jnp.full((nq, B, KV, G, qc), NEG_INF, jnp.float32),
         jnp.zeros((nq, B, KV, G, qc), jnp.float32),
-        jnp.zeros((nq, B, KV, G, qc, hd), jnp.float32)))
+        jnp.zeros((nq, B, KV, G, qc, dv), jnp.float32)))
     (m_t, l_t, acc_t), _ = jax.lax.scan(body, (m0, l0, a0), pairs_arr)
     l_t = jnp.maximum(l_t, 1e-30)
-    out = (acc_t / l_t[..., None]).astype(q.dtype)  # (nq, B, KV, G, qc, hd)
-    return out.transpose(1, 0, 4, 2, 3, 5).reshape(B, Sq, H, hd)
+    out = (acc_t / l_t[..., None]).astype(q.dtype)  # (nq, B, KV, G, qc, dv)
+    return out.transpose(1, 0, 4, 2, 3, 5).reshape(B, Sq, H, dv)
 
 
-def decode_attention(q, k_cache, v_cache, k_pos, cur_pos, *, window=None):
+def decode_attention(q, k_cache, v_cache, k_pos, cur_pos, *, window=None,
+                     scale=None):
     """One-token attention against a cache.
 
-    q: (B, H, hd); k_cache/v_cache: (B, S, KV, hd);
+    q: (B, H, hd); k_cache: (B, S, KV, hd); v_cache: (B, S, KV, dv);
     k_pos: (S,) int32 positions held in each cache slot (-1 = empty);
     cur_pos: scalar int32 — position of the query token.
     """
     B, H, hd = q.shape
     _, S, KV, _ = k_cache.shape
+    dv = v_cache.shape[-1]
     G = H // KV
-    qf = q.reshape(B, KV, G, hd).astype(jnp.float32) * hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
+    qf = q.reshape(B, KV, G, hd).astype(jnp.float32) * scale
     s = jnp.einsum("bkgd,bskd->bkgs", qf, k_cache.astype(jnp.float32))
     valid = (k_pos >= 0) & (k_pos <= cur_pos)
     if window is not None:
@@ -213,4 +223,4 @@ def decode_attention(q, k_cache, v_cache, k_pos, cur_pos, *, window=None):
     s = jnp.where(valid[None, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgs,bskd->bkgd", p, v_cache.astype(jnp.float32))
-    return out.reshape(B, H, hd).astype(q.dtype)
+    return out.reshape(B, H, dv).astype(q.dtype)

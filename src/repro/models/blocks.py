@@ -1,15 +1,20 @@
 """Block-level init/apply/decode dispatch.
 
-Block kinds: attn | local_attn | cross_attn | mamba2 | rglru | xdec.
-Every block is pre-norm residual; attn/rglru/xdec blocks are followed by
-an MLP sub-layer (dense or MoE); mamba2 blocks have none when d_ff == 0.
+Block kinds: attn | attn_dense | local_attn | cross_attn | mamba2 |
+rglru | xdec | mla_dense | mla_moe. Every block is pre-norm residual;
+attn/rglru/xdec blocks are followed by an MLP sub-layer (the MoE where
+``cfg.moe`` is set, else dense); mamba2 blocks have none when d_ff == 0.
+A kind that names its MLP (``*_dense``: the dense MLP of width d_ff,
+``*_moe``: the MoE) takes that one whatever ``cfg.moe`` says, so a
+DeepSeekMoE stack's leading dense layers are blocks of their own kind.
+The mla kinds attend by multi-head latent attention (``models.mla``).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.models import attention, common, mlp, rglru, ssm
+from repro.models import attention, common, mla, mlp, rglru, ssm
 from repro.models.mlp import NO_DIST
 
 
@@ -119,29 +124,26 @@ def precompute_cross_kv(p, cfg, aux):
 # MLP sub-layer dispatch
 # ---------------------------------------------------------------------------
 
-def mlp_init(key, cfg):
-    if cfg.moe is not None:
-        return moe_wrap_init(key, cfg)
+DENSE_KINDS = ("attn_dense", "mla_dense")
+MLA_KINDS = ("mla_dense", "mla_moe")
+
+
+def mlp_init(key, cfg, kind="attn"):
+    if kind == "mla_moe" or (cfg.moe is not None
+                             and kind not in DENSE_KINDS):
+        return mlp.moe_init(key, cfg.moe, cfg.d_model, common.dtype_of(cfg))
     if cfg.d_ff == 0:
         return None
     return mlp.dense_mlp_init(key, cfg.d_model, cfg.d_ff,
                               common.dtype_of(cfg))
 
 
-def moe_wrap_init(key, cfg):
-    import dataclasses
-    m = cfg.moe
-    if m.num_shared:
-        m = dataclasses.replace(m, shared_ff=m.shared_ff)  # copy
-    return mlp.moe_init(key, cfg.moe, cfg.d_model, common.dtype_of(cfg))
-
-
-def mlp_apply(p, cfg, x, dist=NO_DIST):
-    """Returns (y, aux_loss)."""
+def mlp_apply(p, cfg, x, dist=NO_DIST, stats=None):
+    """Returns (y, aux_loss). ``stats``: see ``mlp.moe_apply``."""
     if p is None:
         return jnp.zeros_like(x), jnp.zeros((), jnp.float32)
-    if cfg.moe is not None:
-        return mlp.moe_apply(p, x, cfg.moe, cfg.act, dist)
+    if "router" in p:
+        return mlp.moe_apply(p, x, cfg.moe, cfg.act, dist, stats)
     return mlp.dense_mlp_apply(p, x, cfg.act), jnp.zeros((), jnp.float32)
 
 
@@ -152,9 +154,12 @@ def mlp_apply(p, cfg, x, dist=NO_DIST):
 def block_init(key, cfg, kind):
     dnorm = jnp.zeros((cfg.d_model,), jnp.float32)
     k1, k2, k3, k4 = jax.random.split(key, 4)
-    if kind in ("attn", "local_attn"):
+    if kind in MLA_KINDS:
+        return {"norm1": dnorm, "attn": mla.init(k1, cfg),
+                "norm2": dnorm, "mlp": mlp_init(k2, cfg, kind)}
+    if kind in ("attn", "attn_dense", "local_attn"):
         p = {"norm1": dnorm, "attn": attn_init(k1, cfg)}
-        m = mlp_init(k2, cfg)
+        m = mlp_init(k2, cfg, kind)
         if m is not None:
             p["norm2"] = dnorm
             p["mlp"] = m
@@ -193,13 +198,18 @@ def _window_for(cfg, kind):
 
 
 def block_apply(p, cfg, kind, x, ctx):
-    """x: (B, S, d). ctx: dict(causal, aux, dist, q_offset).
-    Returns (x_out, aux_loss)."""
+    """x: (B, S, d). ctx: dict(causal, aux, dist, q_offset, moe_stats).
+    Returns (x_out, aux_loss). A list under ``moe_stats`` receives each
+    dropless MoE layer's held-expert assignment counts (outside a scan
+    only: they are values of the trace that applies the block)."""
     dist = ctx.get("dist", NO_DIST)
     aux_loss = jnp.zeros((), jnp.float32)
     causal = ctx.get("causal", True)
     q_off = ctx.get("q_offset", 0)
-    if kind in ("attn", "local_attn"):
+    if kind in MLA_KINDS:
+        h = common.rms_norm(x, p["norm1"], cfg.norm_eps)
+        x = x + mla.apply(p["attn"], cfg, h, causal=causal, q_offset=q_off)
+    elif kind in ("attn", "attn_dense", "local_attn"):
         h = common.rms_norm(x, p["norm1"], cfg.norm_eps)
         x = x + attn_apply(p["attn"], cfg, h, causal=causal,
                            window=_window_for(cfg, kind), q_offset=q_off)
@@ -224,7 +234,8 @@ def block_apply(p, cfg, kind, x, ctx):
         raise ValueError(kind)
     if "mlp" in p:
         h = common.rms_norm(x, p["norm2"], cfg.norm_eps)
-        y, aux_loss = mlp_apply(p["mlp"], cfg, h, dist)
+        y, aux_loss = mlp_apply(p["mlp"], cfg, h, dist,
+                                ctx.get("moe_stats"))
         x = x + y
     return x, aux_loss
 
@@ -273,7 +284,12 @@ def block_prefill(p, cfg, kind, x, ctx):
     """x: (B, S, d). Returns (x_out, cache) — cache matches block_decode."""
     max_len = ctx.get("max_len", x.shape[1])
     dist = ctx.get("dist", NO_DIST)
-    if kind in ("attn", "local_attn"):
+    if kind in MLA_KINDS:
+        h = common.rms_norm(x, p["norm1"], cfg.norm_eps)
+        y, cache = mla.apply(p["attn"], cfg, h, return_cache=True,
+                             max_len=max_len)
+        x = x + y
+    elif kind in ("attn", "attn_dense", "local_attn"):
         window = _window_for(cfg, kind)
         h = common.rms_norm(x, p["norm1"], cfg.norm_eps)
         y, cache = _attn_prefill(p["attn"], cfg, h, window=window,
@@ -325,7 +341,9 @@ def block_cache_init(cfg, kind, batch, max_len, dtype):
                 "v": jnp.zeros((batch, length, KV, hd), dtype),
                 "kpos": jnp.full((length,), -1, jnp.int32)}
 
-    if kind == "attn":
+    if kind in MLA_KINDS:
+        return mla.cache_init(cfg, batch, max_len, dtype)
+    if kind in ("attn", "attn_dense"):
         return kv_cache(max_len if cfg.window is None
                         else min(cfg.window, max_len))
     if kind == "local_attn":
@@ -346,7 +364,11 @@ def block_cache_init(cfg, kind, batch, max_len, dtype):
 
 def block_decode(p, cfg, kind, cache, x, pos, ctx):
     """x: (B, d) one token. Returns (x_out, new_cache)."""
-    if kind in ("attn", "local_attn"):
+    if kind in MLA_KINDS:
+        h = common.rms_norm(x, p["norm1"], cfg.norm_eps)
+        y, cache = mla.decode(p["attn"], cfg, cache, h, pos)
+        x = x + y
+    elif kind in ("attn", "attn_dense", "local_attn"):
         window = _window_for(cfg, kind)
         ring = window is not None
         h = common.rms_norm(x, p["norm1"], cfg.norm_eps)

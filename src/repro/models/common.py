@@ -1,6 +1,8 @@
 """Shared numerics: norms, RoPE, initializers, activations."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -42,18 +44,61 @@ def softcap(x, cap):
 # RoPE
 # ---------------------------------------------------------------------------
 
-def rope_freqs(head_dim, theta):
+def rope_freqs(head_dim, theta, scaling=None):
+    """Inverse frequencies of the rotary pairs; with ``scaling`` (a
+    ``YaRNConfig``) YaRN's blend of interpolated and original
+    frequencies (DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``)."""
     half = head_dim // 2
-    return 1.0 / (theta ** (np.arange(0, half, dtype=np.float32) / half))
+    inv = 1.0 / (theta ** (np.arange(0, half, dtype=np.float32) / half))
+    if scaling is None:
+        return inv
+
+    def corr_dim(rotations):
+        return (head_dim * math.log(
+            scaling.original_max_position_embeddings
+            / (rotations * 2 * math.pi))) / (2 * math.log(theta))
+
+    lo = max(math.floor(corr_dim(scaling.beta_fast)), 0)
+    hi = min(math.ceil(corr_dim(scaling.beta_slow)), head_dim - 1)
+    if lo == hi:
+        hi += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float32) - lo) / (hi - lo),
+                   0.0, 1.0)
+    extra_mask = 1.0 - ramp
+    return ((inv / scaling.factor) * (1.0 - extra_mask)
+            + inv * extra_mask).astype(np.float32)
 
 
-def apply_rope(x, positions, theta):
-    """x: (..., S, H, hd); positions: (..., S) int32."""
+def yarn_mscale(factor, mscale):
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_mscale(scaling):
+    """YaRN's factor on cos and sin (1 where mscale == mscale_all_dim,
+    as in DeepSeek-V2)."""
+    if scaling is None:
+        return 1.0
+    return (yarn_mscale(scaling.factor, scaling.mscale)
+            / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+
+
+def attn_scale_factor(scaling):
+    """YaRN's factor on the softmax scale: mscale(factor,
+    mscale_all_dim) squared (1 without scaling)."""
+    if scaling is None or not scaling.mscale_all_dim:
+        return 1.0
+    return yarn_mscale(scaling.factor, scaling.mscale_all_dim) ** 2
+
+
+def apply_rope(x, positions, theta, scaling=None):
+    """x: (..., S, H, hd); positions: (..., S) int32. Pairs dimension i
+    with i + hd/2 (split halves)."""
     hd = x.shape[-1]
-    inv = jnp.asarray(rope_freqs(hd, theta))                   # (hd/2,)
+    inv = jnp.asarray(rope_freqs(hd, theta, scaling))          # (hd/2,)
     ang = positions[..., None].astype(jnp.float32) * inv       # (..., S, hd/2)
-    cos = jnp.cos(ang)[..., None, :]                           # (..., S, 1, hd/2)
-    sin = jnp.sin(ang)[..., None, :]
+    m = rope_mscale(scaling)
+    cos = (jnp.cos(ang) * m)[..., None, :]                     # (..., S, 1, hd/2)
+    sin = (jnp.sin(ang) * m)[..., None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -13,6 +13,13 @@ For decode-sized token counts a dense-local-experts path is used (every
 device runs its local experts over all tokens, psum over 'model'): this
 matches real decode behaviour — memory-bound on expert weights — and
 avoids degenerate capacities.
+
+A configuration without a capacity (``MoEConfig.capacity_factor`` None)
+drops no token: the router scores every expert, and the layer computes
+the part of the routed sum that the experts it holds give
+(``MoEConfig.held``, ``shard``) for every assignment to them, sorted by
+expert and multiplied group by group (``_moe_held``). It runs on one
+chip, without the exchange expert parallelism adds between chips.
 """
 from __future__ import annotations
 
@@ -89,14 +96,15 @@ def dense_mlp_apply(p, x, act_name="silu"):
 
 def moe_init(key, moe, d_model, dtype):
     k1, k2, k3, k4, k5 = jax.random.split(key, 5)
+    E = moe.held_experts
     p = {
         "router": common.dense_init(k1, (d_model, moe.num_experts),
                                     jnp.float32),
-        "wi": common.dense_init(k2, (moe.num_experts, d_model, moe.expert_ff),
+        "wi": common.dense_init(k2, (E, d_model, moe.expert_ff),
                                 dtype, fan_in=d_model),
-        "wg": common.dense_init(k3, (moe.num_experts, d_model, moe.expert_ff),
+        "wg": common.dense_init(k3, (E, d_model, moe.expert_ff),
                                 dtype, fan_in=d_model),
-        "wo": common.dense_init(k4, (moe.num_experts, moe.expert_ff, d_model),
+        "wo": common.dense_init(k4, (E, moe.expert_ff, d_model),
                                 dtype, fan_in=moe.expert_ff),
     }
     if moe.num_shared:
@@ -114,7 +122,8 @@ def _router(p, x, moe):
     logits = x.astype(jnp.float32) @ p["router"]
     probs = jax.nn.softmax(logits, axis=-1)
     gate, eids = jax.lax.top_k(probs, moe.top_k)           # (T, k)
-    gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    if moe.norm_topk:
+        gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
     # switch-style load-balance loss (computed locally, pmean'd by caller)
     me = probs.mean(axis=0)                                # (E,)
     one_hot = jax.nn.one_hot(eids[:, 0], moe.num_experts, dtype=jnp.float32)
@@ -161,6 +170,35 @@ def _expert_ffn(wi, wg, wo, tokens, act_name):
     h = act(jnp.einsum("ecd,edf->ecf", tokens, wg))
     h = h * jnp.einsum("ecd,edf->ecf", tokens, wi)
     return jnp.einsum("ecf,efd->ecd", h, wo)
+
+
+def _moe_held(p, x, moe, act_name):
+    """Every assignment to a held expert, none dropped. x: (T, d).
+    Returns (y, aux, counts): counts (held,) are the assignments to each
+    held expert."""
+    T, d = x.shape
+    k, held = moe.top_k, moe.held_experts
+    gate, eids, aux = _router(p, x, moe)
+    local = eids.reshape(-1) - moe.shard * held
+    group = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(group)                             # stable
+    counts = jnp.bincount(group, length=held + 1)[:held]
+    # the first sum(counts) sorted rows are the held experts' groups;
+    # the rows after them belong to no group and stay zero both ways
+    rows = jnp.arange(T * k) < jnp.sum(counts)
+    tok = order // k
+    xs = jnp.where(rows[:, None], x[tok], 0)
+
+    def grouped(a, w):
+        return jax.lax.ragged_dot(a, w, counts)
+
+    act = common.activation(act_name)
+    h = act(grouped(xs, p["wg"])) * grouped(xs, p["wi"])
+    out = jnp.where(rows[:, None], grouped(h, p["wo"]), 0)
+    g = jnp.where(rows, gate.reshape(-1)[order], 0)
+    y = jnp.zeros((T, d), jnp.float32).at[tok].add(
+        out.astype(jnp.float32) * g[:, None])
+    return y.astype(x.dtype), aux, counts
 
 
 def _moe_local(p, x, moe, act_name, dist: Dist):
@@ -226,9 +264,25 @@ def _moe_local(p, x, moe, act_name, dist: Dist):
     return y, aux
 
 
-def moe_apply(p, x, moe, act_name, dist: Dist = NO_DIST):
-    """x: (B, S, d) global (pjit-land). Returns (y, aux_loss)."""
+def moe_apply(p, x, moe, act_name, dist: Dist = NO_DIST, stats=None):
+    """x: (B, S, d) global (pjit-land). Returns (y, aux_loss). Without a
+    capacity, ``stats`` (a list, if given) receives the held experts'
+    assignment counts."""
     B, S, d = x.shape
+    if moe.capacity_factor is None:
+        if dist.enabled:
+            raise NotImplementedError(
+                "the dropless held-expert MoE runs on one chip, without "
+                "the exchange between chips")
+        y, aux, counts = _moe_held(p, x.reshape(-1, d), moe, act_name)
+        if "shared" in p:
+            y = y + dense_mlp_apply(p["shared"], x.reshape(-1, d), act_name)
+        if stats is not None:
+            stats.append(counts)
+        return y.reshape(x.shape), aux
+    if moe.held:
+        raise NotImplementedError("a layer holding part of the experts "
+                                  "routes without a capacity")
 
     def body(p_, x_):
         xt = x_.reshape(-1, d)

@@ -128,10 +128,10 @@ def unembed(params, cfg, x):
     return logits
 
 
-def forward(params, cfg, tokens, *, aux=None, dist=NO_DIST, remat=None):
+def hidden(params, cfg, tokens, *, aux=None, dist=NO_DIST, remat=None):
     """tokens: (B, S) int32; aux: (B, T, d) stub embeddings (audio/vlm).
 
-    Returns (logits (B, S, V) f32, aux_loss scalar).
+    Returns (the last block's output (B, S, d), aux_loss scalar).
     """
     remat = cfg.remat if remat is None else remat
     aux_loss = jnp.zeros((), jnp.float32)
@@ -145,6 +145,16 @@ def forward(params, cfg, tokens, *, aux=None, dist=NO_DIST, remat=None):
             continue
         x, a = scan_group(params["groups"][gi], cfg, pattern, x, ctx, remat)
         aux_loss += a
+    return x, aux_loss
+
+
+def forward(params, cfg, tokens, *, aux=None, dist=NO_DIST, remat=None):
+    """tokens: (B, S) int32; aux: (B, T, d) stub embeddings (audio/vlm).
+
+    Returns (logits (B, S, V) f32, aux_loss scalar).
+    """
+    x, aux_loss = hidden(params, cfg, tokens, aux=aux, dist=dist,
+                         remat=remat)
     return unembed(params, cfg, x), aux_loss
 
 
