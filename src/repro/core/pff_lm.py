@@ -249,6 +249,16 @@ def load_attrs(counts):
                                         / max(per_expert.mean(), 1e-9))}
 
 
+def attn_attrs(cfg, pattern, seq):
+    """Span attr of a block task over sequences of ``seq`` tokens:
+    ``attn_pair_share``, the share of its causal self-attention's
+    (q chunk, k chunk) pairs that the chunked scan computes
+    (``blocks.self_attn_pairs``); none for blocks without attention."""
+    run, whole = np.sum([blocks.self_attn_pairs(cfg, kind, seq)
+                         for kind in pattern], axis=0)
+    return {"attn_pair_share": float(run / whole)} if whole else {}
+
+
 def train_chapters(cfg, data_iter_fn, *, chapters, steps_per_chapter,
                    lr=1e-3, head_lr=None, seed=0, tracer=obs_trace.NOOP):
     """Runs the chapter schedule; returns (params, records, losses,
@@ -267,7 +277,8 @@ def train_chapters(cfg, data_iter_fn, *, chapters, steps_per_chapter,
     alone, for its record.
 
     Spans (``tracer``): ``fit:init``; per task ``task:train`` (layer,
-    chapter, steps, kind; on a traced MoE block also ``routed`` and
+    chapter, steps, kind; traced, also ``attn_pair_share``,
+    ``attn_attrs``, and on a MoE block ``routed`` and
     ``load_max_over_mean``, ``load_attrs``) with ``task:wait`` inside,
     and ``task:head``.
     """
@@ -305,6 +316,10 @@ def train_chapters(cfg, data_iter_fn, *, chapters, steps_per_chapter,
                 with obs_trace.annotate("task:wait"):
                     jax.block_until_ready(bp[k])
                 losses.append(jnp.stack(step_losses))
+                if tracer.enabled:
+                    # the block step drops each sequence's last token
+                    attrs.update(attn_attrs(cfg, pattern,
+                                            batch["tokens"].shape[1] - 1))
                 if tracer.enabled and counts[0].size:
                     attrs.update(load_attrs(counts))
                 records.append(TaskRecord("train", k, c,

@@ -9,10 +9,13 @@ validated against this path.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 NEG_INF = -1e30
+Q_CHUNK, K_CHUNK = 512, 1024   # the chunked scan's default chunk sizes
 
 # §Perf toggles (set by launch/dryrun.py opts; read at trace time).
 DEFAULT_CAUSAL_SKIP = False
@@ -52,8 +55,86 @@ def _mask(q_pos, k_pos, causal, window):
     return m
 
 
+def _chunk_sizes(Sq, Sk, q_chunk, k_chunk):
+    """The chunk sizes the scan uses: at most the sequence, and the
+    whole sequence where the chunk does not divide it."""
+    q_chunk, k_chunk = min(q_chunk, Sq), min(k_chunk, Sk)
+    return (Sq if Sq % q_chunk else q_chunk), (Sk if Sk % k_chunk else k_chunk)
+
+
+def _k_chunks_run(Sq, Sk, q_chunk, k_chunk, causal, window, q_offset,
+                  k_offset):
+    """How many k chunks each q chunk folds in, or None where every
+    pair runs. Engaged for causal attention without a window whose
+    static offsets put key ``k_offset`` (k chunk 0) before every query
+    (``q_offset >= k_offset``): each row then has a finite running max
+    before the first pair it skips, and a pair whose first key lies
+    after the q chunk's last query adds exact zeros to its softmax.
+    Such pairs are a suffix of each q chunk's k chunks."""
+    if not (causal and window is None and isinstance(q_offset, int)
+            and isinstance(k_offset, int) and q_offset >= k_offset):
+        return None
+    qc, kc = _chunk_sizes(Sq, Sk, q_chunk, k_chunk)
+    last = q_offset - k_offset + qc - 1   # q chunk 0's last query, in k
+    return [min(Sk // kc, (last + i * qc) // kc + 1)
+            for i in range(Sq // qc)]
+
+
+def chunk_pairs(Sq, Sk, q_chunk=Q_CHUNK, k_chunk=K_CHUNK, *, causal=True,
+                window=None, q_offset=0, k_offset=0):
+    """(pairs run, pairs whole): the (q chunk, k chunk) pairs that
+    ``chunked_attention`` computes with these arguments, and the
+    ``nq * nk`` of the whole double scan. Causal self-attention at
+    4,096 with 512/1024 chunks runs 20 of 32."""
+    qc, kc = _chunk_sizes(Sq, Sk, q_chunk, k_chunk)
+    whole = (Sq // qc) * (Sk // kc)
+    run = _k_chunks_run(Sq, Sk, q_chunk, k_chunk, causal, window, q_offset,
+                        k_offset)
+    return (whole if run is None else sum(run)), whole
+
+
+def _k_chunk_prefix(attend, nk):
+    """``attend(qc, q_pos, ks, vs, k_pos)`` over the first ``n`` of the
+    ``nk`` k chunks alone, ``n`` given at run time: a switch over the
+    static lengths. The forward reads those chunks in place. The VJP
+    recomputes the branch it takes on a slice of them, so a q chunk's
+    backward holds the residuals and k/v gradients of its own pairs
+    alone (read in place, each pair's k/v gradient would add into a
+    whole-K and a whole-V buffer); a plain ``lax.switch`` (or a
+    ``lax.cond`` per pair) under the remat would return every branch's
+    residuals, zero-filled."""
+    fwd = [functools.partial(attend, n=n) for n in range(1, nk + 1)]
+    bwd = [functools.partial(_prefix_vjp, attend, n)
+           for n in range(1, nk + 1)]
+
+    @jax.custom_vjp
+    def run(n, qc, q_pos, ks, vs, k_pos):
+        return jax.lax.switch(n - 1, fwd, qc, q_pos, ks, vs, k_pos)
+
+    def run_fwd(n, qc, q_pos, ks, vs, k_pos):
+        return run(n, qc, q_pos, ks, vs, k_pos), (n, qc, q_pos, ks, vs,
+                                                   k_pos)
+
+    def run_bwd(res, ct):
+        n, qc, q_pos, ks, vs, k_pos = res
+        return (None, *jax.lax.switch(n - 1, bwd, qc, q_pos, ks, vs, k_pos,
+                                      ct))
+
+    run.defvjp(run_fwd, run_bwd)
+    return run
+
+
+def _prefix_vjp(attend, n, qc, q_pos, ks, vs, k_pos, ct):
+    """(dqc, None, dks, dvs, None): the cotangents ``run_bwd`` returns,
+    of ``attend`` over the first ``n`` k chunks."""
+    _, vjp = jax.vjp(lambda qc, ks, vs: attend(qc, q_pos, ks[:n], vs[:n],
+                                               k_pos[:n]), qc, ks, vs)
+    dq, dk, dv = vjp(ct)
+    return dq, None, dk, dv, None
+
+
 def chunked_attention(q, k, v, *, causal=True, window=None, q_offset=0,
-                      k_offset=0, q_chunk=512, k_chunk=1024,
+                      k_offset=0, q_chunk=Q_CHUNK, k_chunk=K_CHUNK,
                       causal_skip=False, scale=None):
     """q: (B, Sq, H, hd); k: (B, Sk, KV, hd); v: (B, Sk, KV, dv).
     Returns (B, Sq, H, dv). ``scale`` defaults to hd ** -0.5.
@@ -62,6 +143,9 @@ def chunked_attention(q, k, v, *, causal=True, window=None, q_offset=0,
     buffer is (B, H, q_chunk, k_chunk) regardless of sequence length.
     Each q chunk is rematerialised in the backward pass: kept, its
     chunk pairs' scores would hold the whole (B, H, Sq, Sk) matrix.
+    Where the causal mask hides every score of a pair, the pair is not
+    computed (``chunk_pairs`` counts them); the visible pairs fold in
+    the same order, so results are the same to the bit.
 
     ``causal_skip`` (a §Perf optimization, off by default): instead of
     the dense nq x nk double scan, enumerate only the VISIBLE (q, k)
@@ -78,13 +162,11 @@ def chunked_attention(q, k, v, *, causal=True, window=None, q_offset=0,
     _, Sk, KV, _ = k.shape
     dv = v.shape[-1]
     G = H // KV
-    q_chunk = min(q_chunk, Sq)
-    k_chunk = min(k_chunk, Sk)
-    if Sq % q_chunk:
-        q_chunk = Sq
-    if Sk % k_chunk:
-        k_chunk = Sk
+    runs = _k_chunks_run(Sq, Sk, q_chunk, k_chunk, causal, window,
+                         q_offset, k_offset)
+    q_chunk, k_chunk = _chunk_sizes(Sq, Sk, q_chunk, k_chunk)
     nq, nk = Sq // q_chunk, Sk // k_chunk
+    skip = runs is not None and sum(runs) < nq * nk
     scale = hd ** -0.5 if scale is None else scale
 
     # (nq, B, qc, KV, G, hd)
@@ -94,17 +176,25 @@ def chunked_attention(q, k, v, *, causal=True, window=None, q_offset=0,
     q_positions = q_offset + jnp.arange(Sq, dtype=jnp.int32)
     k_positions = k_offset + jnp.arange(Sk, dtype=jnp.int32)
 
-    def q_body(_, qi):
-        qc, q_pos = qi                       # (B, qc, KV, G, hd), (qc,)
+    def attend(qc, q_pos, ks, vs, k_positions, n=None):
+        """One q chunk, online softmax over the k chunks given, or over
+        the first ``n`` of them, read in place."""
         qcf = qc.astype(jnp.float32) * scale
 
         def k_body(carry, ki):
             m_run, l_run, acc = carry
+            if n is not None:                # ki: the chunk's index
+                ki = [jax.lax.dynamic_index_in_dim(a, ki, 0, False)
+                      for a in (ks, vs, k_positions)]
             kc, vc, k_pos = ki               # (B, kc, KV, hd)
             s = jnp.einsum("bqkgd,bskd->bkgqs", qcf,
                            kc.astype(jnp.float32))     # (B, KV, G, qc, kc)
             msk = _mask(q_pos, k_pos, causal, window)  # (qc, kc)
-            s = jnp.where(msk[None, None, None], s, NEG_INF)
+            if skip:    # masked scores round to NEG_INF exactly, as
+                # with the select, but the grad keeps no mask per pair
+                s = s + jnp.where(msk, 0.0, NEG_INF)[None, None, None]
+            else:
+                s = jnp.where(msk[None, None, None], s, NEG_INF)
             m_new = jnp.maximum(m_run, s.max(axis=-1))
             p = jnp.exp(s - m_new[..., None])
             corr = jnp.exp(m_run - m_new)
@@ -118,13 +208,22 @@ def chunked_attention(q, k, v, *, causal=True, window=None, q_offset=0,
             jnp.zeros((B, KV, G, q_chunk), jnp.float32),
             jnp.zeros((B, KV, G, q_chunk, dv), jnp.float32)))
         (m, l, acc), _ = jax.lax.scan(
-            k_body, (m0, l0, a0), (ks, vs, k_positions.reshape(nk, k_chunk)))
+            k_body, (m0, l0, a0),
+            (ks, vs, k_positions) if n is None else jnp.arange(n))
         l = jnp.maximum(l, 1e-30)
         out = (acc / l[..., None]).astype(q.dtype)     # (B, KV, G, qc, dv)
-        return None, out.transpose(0, 3, 1, 2, 4)      # (B, qc, KV, G, dv)
+        return out.transpose(0, 3, 1, 2, 4)            # (B, qc, KV, G, dv)
 
-    _, outs = jax.lax.scan(
-        jax.checkpoint(q_body), None, (qs, q_positions.reshape(nq, q_chunk)))
+    q_xs = (qs, q_positions.reshape(nq, q_chunk))
+    k_xs = (ks, vs, k_positions.reshape(nk, k_chunk))
+    if skip:    # q chunk i folds in k chunks 0 .. runs[i] - 1
+        attend = _k_chunk_prefix(attend, nk)
+        q_xs = (jnp.asarray(runs, jnp.int32),) + q_xs
+
+    def q_body(_, qi):
+        return None, attend(*qi, *k_xs)
+
+    _, outs = jax.lax.scan(jax.checkpoint(q_body), None, q_xs)
     # (nq, B, qc, KV, G, dv) -> (B, Sq, H, dv)
     return outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, Sq, H, dv)
 

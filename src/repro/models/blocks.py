@@ -197,6 +197,17 @@ def _window_for(cfg, kind):
     return cfg.window
 
 
+def self_attn_pairs(cfg, kind, seq):
+    """(pairs run, pairs whole) of the chunked causal self-attention a
+    block of ``kind`` runs over ``seq`` positions from 0
+    (``attention.chunk_pairs``); (0, 0) for a kind without one."""
+    if kind in MLA_KINDS:
+        return attention.chunk_pairs(seq, seq)
+    if kind in ("attn", "attn_dense", "local_attn"):
+        return attention.chunk_pairs(seq, seq, window=_window_for(cfg, kind))
+    return 0, 0
+
+
 def block_apply(p, cfg, kind, x, ctx):
     """x: (B, S, d). ctx: dict(causal, aux, dist, q_offset, moe_stats).
     Returns (x_out, aux_loss). A list under ``moe_stats`` receives each

@@ -289,7 +289,8 @@ def test_tuned_blocks_reach_forced_pallas(key):
         {"impl": "ref", "time_s": 0.1, "err": 0.0, "grad_err": 0.0,
          "bm": 8, "bn": 128, "pallas_time_s": 0.2})
     seen = {}
-    orig = registry.ff_dense.get("pallas").fn
+    builtin = registry.ff_dense.get("pallas")
+    orig = builtin.fn
 
     def spy(x, w, b, **kw):
         seen["blocks"] = kw["blocks"]
@@ -305,8 +306,9 @@ def test_tuned_blocks_reach_forced_pallas(key):
         assert seen["blocks"] == (8, 128, None)
         yr, gr = ref.ff_dense_ref(x, w, b)
         np.testing.assert_allclose(y, yr, rtol=1e-5, atol=1e-5)
-    finally:
+    finally:    # the builtin back whole: "auto" prefers it on a TPU
         registry.register_kernel_impl("ff_dense", "pallas", orig,
+                                      preferred=builtin.preferred,
                                       tunable=True, overwrite=True)
 
 

@@ -236,8 +236,9 @@ def test_benchmark_copy_of_the_reference_is_the_same_file():
 
 def test_traced_fit_names_the_lm_spans_and_counts_the_held_experts(cfg):
     """``api.fit`` on the two-group stack with a tracer: ``fit:init``,
-    one ``task:train`` per block (its kind; MoE blocks also the
-    assignments to held experts and their load spread), ``task:head``,
+    one ``task:train`` per block (its kind and attention's pair share;
+    MoE blocks also the assignments to held experts and their load
+    spread), ``task:head``,
     ``fit:eval``, and the per-step FF losses in ``history``."""
     from repro import api
     source = data_lib.text_source(vocab=cfg.vocab, seq_len=16, seed=0)
@@ -250,9 +251,25 @@ def test_traced_fit_names_the_lm_spans_and_counts_the_held_experts(cfg):
     train = [s.attrs for s in spans if s.name == "task:train"]
     assert [a["kind"] for a in train] == ["mla_dense", "mla_moe", "mla_moe"]
     assert "routed" not in train[0]
+    for a in train:                             # one 16 x 16 chunk pair
+        assert a["attn_pair_share"] == 1.0
     tokens = 2 * 2 * 16 * 2                     # pos + neg, 2 steps
     for a in train[1:]:
         assert 0 < a["routed"] <= tokens * cfg.moe.top_k
         assert a["load_max_over_mean"] >= 1.0
     assert [n for n, _ in res.history] == list(range(1, 7))
     assert len(res.head_history) == 2
+
+
+def test_block_task_attn_pair_share_follows_the_sequence(cfg):
+    """A block task's ``attn_pair_share`` is the chunked scan's pairs
+    run over its pairs whole: 20 of 32 for MLA at 4,096 tokens (512/1024
+    chunks), every pair where one chunk holds the sequence, and no attr
+    for a block without attention."""
+    assert pff_lm.attn_attrs(cfg, ("mla_moe",), 4096) == {
+        "attn_pair_share": 0.625}
+    assert pff_lm.attn_attrs(cfg, ("mla_dense", "mla_moe"), 2048) == {
+        "attn_pair_share": 0.75}
+    assert pff_lm.attn_attrs(cfg, ("mla_dense",), 16) == {
+        "attn_pair_share": 1.0}
+    assert pff_lm.attn_attrs(cfg, ("mamba2",), 4096) == {}
